@@ -20,10 +20,12 @@ regime of real FL, where clients run many local steps). With
 ``local_steps=1`` a single device can fuse the whole cohort's forward pass
 into one multithreaded GEMM and sharding has nothing left to win on CPU.
 
-When the current process lacks the requested device count (e.g. invoked
-from benchmarks/run.py after JAX already initialised the single real CPU
-device), the benchmark re-executes itself in a subprocess with XLA_FLAGS
-set, streams its output, and returns the parsed results.
+On the CPU, when the current process lacks the requested device count
+(e.g. invoked from benchmarks/run.py after JAX already initialised the
+single real CPU device), the benchmark re-executes itself in a subprocess
+with XLA_FLAGS set, streams its output, and returns the parsed results.
+On an accelerator it never starts a child (the parent holds the chips):
+it runs in-process on the devices that exist.
 
 Also measures one 2-D ('clients', 'model') mesh point — the FSDP
 configuration where params (and the EF residual store) live 1/M per device
@@ -393,11 +395,18 @@ def run(devices: int = 8, rounds: int = 30, reps: int = 5,
         clients: int = 64, batch: int = 16,
         pop_clients: int = 1_000_000, pop_cohort: int = 4096,
         pop_rounds: int = 3, out=sys.stdout) -> dict:
-    """Entry point for benchmarks/run.py: re-exec with forced devices when
-    this process cannot see enough of them (JAX device count is fixed at
+    """Entry point for benchmarks/run.py.
+
+    On an accelerator the benchmark runs in this process over the devices
+    that exist, with mesh sizes capped at ``jax.device_count()``: a chip
+    belongs to one process, and this one already holds it. On the CPU,
+    when this process sees fewer than ``devices`` devices, it re-executes
+    itself with forced host devices (the device count is fixed at JAX's
     first import; only a fresh process can change it)."""
     import jax
-    if len(jax.devices()) >= devices:
+    if jax.default_backend() != "cpu":
+        devices = min(devices, jax.device_count())
+    if jax.device_count() >= devices:
         return run_local(devices, rounds, reps, clients, batch,
                          pop_clients=pop_clients, pop_cohort=pop_cohort,
                          pop_rounds=pop_rounds, out=out)

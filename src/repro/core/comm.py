@@ -130,10 +130,13 @@ def agg_tier_bytes(payload_bytes: float, axis_size: int,
 # lives in the lax.scan carry, so no per-round device→host pull is needed.
 # ----------------------------------------------------------------------
 def comm_acc_init() -> dict:
-    """Zeroed jit-safe accumulator matching :class:`CommMeter`'s totals."""
-    z = jnp.float32(0.0)
-    return {"uplink_bytes": z, "downlink_bytes": z,
-            "fedavg_uplink_bytes": z, "rounds": z}
+    """Zeroed jit-safe accumulator matching :class:`CommMeter`'s totals.
+
+    One buffer per entry: the scan driver donates its carry, and a buffer
+    that appears twice in a donated argument is refused."""
+    return {name: jnp.float32(0.0) for name in
+            ("uplink_bytes", "downlink_bytes", "fedavg_uplink_bytes",
+             "rounds")}
 
 
 def comm_acc_update(acc: dict, round_stats: dict) -> dict:

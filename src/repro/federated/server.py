@@ -1012,8 +1012,7 @@ def _gather_rows(store: Pytree, clients: jnp.ndarray) -> Pytree:
 def _scatter_rows(store: Pytree, clients: jnp.ndarray,
                   rows: Pytree) -> Pytree:
     # explicit cast: EF update arithmetic runs fp32, the store keeps each
-    # leaf's own dtype (an implicit fp32->bf16 scatter cast is a
-    # FutureWarning on jax 0.4.x and an error on newer releases)
+    # leaf's own dtype (jax refuses an implicit fp32->bf16 scatter cast)
     return jax.tree.map(
         lambda full, r: full.at[clients].set(r.astype(full.dtype)),
         store, rows)
@@ -1285,12 +1284,9 @@ def _build_block_fn(loss_fn, umap: UnitMap, flcfg: FLConfig):
                 per_round["selection"] = metrics["selection"]
         return (params, state, acc), per_round
 
-    # carry buffers are donated so XLA reuses them across eval blocks; on
-    # CPU donation is a no-op warning, so only request it where it works.
-    donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-
+    # carry buffers are donated so XLA reuses them across eval blocks
     @functools.partial(jax.jit, static_argnames=("num",),
-                       donate_argnums=donate)
+                       donate_argnums=(0,))
     def run_block(carry, shards, all_sizes, base_key, t0, num, frozen=None):
         # ``frozen`` is a real (pytree) argument, not a closure: closed-over
         # arrays would be baked into the jaxpr as constants and re-staged
@@ -1355,13 +1351,13 @@ def run_training_scan(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
                               shard_samples=flcfg.shard_samples)
     merged = ((lambda p: p) if partition is None
               else (lambda p: partition.merge(p, frozen)))
-    if jax.default_backend() in ("tpu", "gpu"):
-        # run_block donates its carry; copy once so the caller's param
-        # buffers survive the first block (state/acc are fresh).
-        params = jax.tree.map(jnp.copy, params)
+    # run_block donates its carry; copy once so the caller's param and
+    # resumed-state buffers survive the first block
+    params = jax.tree.map(jnp.copy, params)
     if server_state is not None:
-        state0 = (_place_state(server_state, params, strategy, flcfg.mesh)
-                  if flcfg.mesh is not None else server_state)
+        state0 = jax.tree.map(jnp.copy, (
+            _place_state(server_state, params, strategy, flcfg.mesh)
+            if flcfg.mesh is not None else server_state))
     else:
         state0 = strategy.init_state(params, flcfg.num_clients, flcfg.mesh)
     carry = (params, state0, comm_mod.comm_acc_init())

@@ -20,6 +20,13 @@ Blocks default to (32, 2048): int8 operands need (32, 128)-aligned tiles
 (fp32 only needs (8, 128)), and one int8 + four fp32 blocks ≈ 0.6 MiB —
 comfortable in the ~16 MiB VMEM budget.
 
+Leaves arrive with few unit rows (an unstacked leaf is one row of C
+elements), so the wrappers never pad rows up to the block: each unit row
+is folded into ``f = block_r / gcd(r, block_r)`` lane-dense sub-rows of
+``C / f`` columns (:func:`_fold`), making ``r·f`` a block multiple, and
+the per-unit scale, weight and gate are repeated per sub-row. Only C pads,
+up to a multiple of ``f·128``; the arithmetic per element is unchanged.
+
 ``interpret=None`` resolves via the backend check in ``kernels/ops``
 (compiled on TPU, interpret elsewhere); ``kernels/ref.py`` holds the
 pure-jnp oracle that doubles as the CPU fast path.
@@ -27,6 +34,7 @@ pure-jnp oracle that doubles as the CPU fast path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,19 +70,36 @@ def _uplink_ef_kernel(lvl_ref, s_ref, w_ref, g_ref, v_ref, e_ref,
                   + (1.0 - g) * e_ref[0].astype(jnp.float32))
 
 
-def _padded(levels, rowvecs, mats, block_r, block_c):
-    """Zero-pad (K,R,C) operands and (K,R) row vectors to block multiples.
-    Zero pads are exact: w=0 rows add nothing to num, gate=0 rows copy the
-    zero-padded residual through."""
+def _fold(levels, rowvecs, mats, block_r, block_c):
+    """Fold (K, r, C) operands into (K, r·f, Cf) lane-dense rows.
+
+    ``f = block_r / gcd(r, block_r)`` makes the row count a block
+    multiple without padding rows; C zero-pads to ``f·Cf`` with ``Cf`` a
+    multiple of 128, and the column block is the largest multiple of 128
+    up to ``block_c`` that divides ``Cf``. (K, r) row vectors repeat per
+    folded row. Zero pads are exact: zero levels add nothing to num, and
+    the residual's pad columns are sliced off by :func:`_unfold`.
+    Returns ``(levels, rowvecs, mats, block_c)``.
+    """
     k, r, c = levels.shape
-    rp = pl.cdiv(r, block_r) * block_r
-    cp = pl.cdiv(c, block_c) * block_c
-    if (rp, cp) != (r, c):
-        levels = jnp.pad(levels, ((0, 0), (0, rp - r), (0, cp - c)))
-        mats = [jnp.pad(m, ((0, 0), (0, rp - r), (0, cp - c))) for m in mats]
-        rowvecs = [jnp.pad(v, ((0, 0), (0, rp - r))) for v in rowvecs]
-    rowvecs = [v.reshape(k, rp, 1) for v in rowvecs]
-    return levels, rowvecs, mats, rp, cp
+    f = block_r // math.gcd(r, block_r)
+    lanes = pl.cdiv(pl.cdiv(c, f), 128)           # Cf in 128-lane units
+    cf = lanes * 128
+    bc = 128 * max(d for d in range(1, max(1, block_c // 128) + 1)
+                   if lanes % d == 0)
+    if f * cf != c:
+        levels = jnp.pad(levels, ((0, 0), (0, 0), (0, f * cf - c)))
+        mats = [jnp.pad(m, ((0, 0), (0, 0), (0, f * cf - c))) for m in mats]
+    levels = levels.reshape(k, r * f, cf)
+    mats = [m.reshape(k, r * f, cf) for m in mats]
+    rowvecs = [jnp.repeat(v, f, axis=1).reshape(k, r * f, 1)
+               for v in rowvecs]
+    return levels, rowvecs, mats, bc
+
+
+def _unfold(x, r, c):
+    """(..., r·f, Cf) → (..., r, C): undo :func:`_fold`'s row split."""
+    return x.reshape(x.shape[:-2] + (r, -1))[..., :c]
 
 
 @functools.partial(jax.jit,
@@ -92,11 +117,10 @@ def fused_uplink(levels: jnp.ndarray, scales: jnp.ndarray, w: jnp.ndarray, *,
         interpret = ops._interpret()
     kk, r, c = levels.shape
     assert scales.shape == (kk, r) and w.shape == (kk, r)
-    block_r = min(block_r, max(32, r))
-    block_c = min(block_c, max(128, c))
-    levels, (s2, w2), _, rp, cp = _padded(levels, [scales, w], [],
-                                          block_r, block_c)
-    grid = (rp // block_r, cp // block_c, kk)
+    levels, (s2, w2), _, block_c = _fold(levels, [scales, w], [],
+                                         block_r, block_c)
+    _, rf, cf = levels.shape
+    grid = (rf // block_r, cf // block_c, kk)
     num = pl.pallas_call(
         _uplink_kernel,
         grid=grid,
@@ -106,10 +130,10 @@ def fused_uplink(levels: jnp.ndarray, scales: jnp.ndarray, w: jnp.ndarray, *,
             pl.BlockSpec((1, block_r, 1), lambda i, j, k: (k, i, 0)),
         ],
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rp, cp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rf, cf), jnp.float32),
         interpret=interpret,
     )(levels, s2, w2)
-    return num[:r, :c]
+    return _unfold(num, r, c)
 
 
 @functools.partial(jax.jit,
@@ -134,11 +158,10 @@ def fused_uplink_ef(levels: jnp.ndarray, scales: jnp.ndarray,
     assert scales.shape == (kk, r) and w.shape == (kk, r)
     assert gate.shape == (kk, r) and v.shape == (kk, r, c)
     assert e_old.shape == (kk, r, c)
-    block_r = min(block_r, max(32, r))
-    block_c = min(block_c, max(128, c))
-    levels, (s2, w2, g2), (v_, e_), rp, cp = _padded(
+    levels, (s2, w2, g2), (v_, e_), block_c = _fold(
         levels, [scales, w, gate], [v, e_old], block_r, block_c)
-    grid = (rp // block_r, cp // block_c, kk)
+    _, rf, cf = levels.shape
+    grid = (rf // block_r, cf // block_c, kk)
     num, res = pl.pallas_call(
         _uplink_ef_kernel,
         grid=grid,
@@ -155,9 +178,9 @@ def fused_uplink_ef(levels: jnp.ndarray, scales: jnp.ndarray,
             pl.BlockSpec((1, block_r, block_c), lambda i, j, k: (k, i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rp, cp), jnp.float32),
-            jax.ShapeDtypeStruct((kk, rp, cp), jnp.float32),
+            jax.ShapeDtypeStruct((rf, cf), jnp.float32),
+            jax.ShapeDtypeStruct((kk, rf, cf), jnp.float32),
         ],
         interpret=interpret,
     )(levels, s2, w2, g2, v_, e_)
-    return num[:r, :c], res[:, :r, :c]
+    return _unfold(num, r, c), _unfold(res, r, c)

@@ -37,14 +37,9 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 def cost_analysis_dict(cost) -> dict:
-    """Normalise ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns one dict per device (a list); current JAX returns the
-    dict directly (or ``None`` on backends without cost analysis). Always
-    returns a plain dict so callers can ``.get("flops", 0.0)``.
-    """
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
+    """``compiled.cost_analysis()`` as a plain dict (``{}`` on backends
+    without cost analysis, which return ``None``), so callers can
+    ``.get("flops", 0.0)``."""
     return dict(cost) if cost else {}
 
 

@@ -184,19 +184,11 @@ def replicated_rng(fn, mesh):
 
 
 def shard_map_norep(f, mesh, in_specs, out_specs):
-    """Version-compatible ``shard_map`` with replication checking off.
+    """``jax.shard_map`` with the replication check off.
 
-    jax moved ``jax.experimental.shard_map`` to top-level ``jax.shard_map``
-    (renaming ``check_rep`` to ``check_vma``); CI's latest-jax leg needs the
-    new spelling while the pinned 0.4.x container needs the old one. The
-    replication check is disabled in both: the static checker cannot follow
-    the axis_index-based row slicing the sharded FL round uses, and output
-    replication is instead covered by equivalence tests
-    (tests/test_shard_engine.py).
+    The static checker cannot follow the axis_index-based row slicing the
+    sharded FL round uses; output replication is instead covered by
+    equivalence tests (tests/test_shard_engine.py).
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -212,6 +212,64 @@ def test_fused_uplink_ef_pallas_matches_ref(monkeypatch, shape):
                                   np.asarray(e_old)[off])
 
 
+# folded layout: a leaf with fewer unit rows than the block folds into
+# lane-dense sub-rows instead of padding rows up to 32
+@pytest.mark.parametrize("ef", [False, True], ids=["noef", "ef"])
+@pytest.mark.parametrize("shape", [(3, 1, 4096), (2, 3, 4096), (4, 1, 1000),
+                                   (3, 3, 777)],
+                         ids=["r1", "r3", "r1-c1000", "r3-c777"])
+def test_fused_uplink_folded_layout_matches_ref(monkeypatch, shape, ef):
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    from repro.kernels import ops as kops
+    k_, r, c = shape
+    ks = jax.random.split(jax.random.PRNGKey(r * 1000 + c), 6)
+    levels = jax.random.randint(ks[0], shape, -127, 128).astype(jnp.int8)
+    scales = jax.random.uniform(ks[1], (k_, r), minval=1e-4)
+    w = jax.random.uniform(ks[2], (k_, r))
+    if not ef:
+        np.testing.assert_allclose(
+            np.asarray(kops.fused_uplink(levels, scales, w)),
+            np.asarray(ref.fused_uplink(levels, scales, w)),
+            rtol=3e-5, atol=1e-5)
+        return
+    gate = (jax.random.uniform(ks[3], (k_, r)) < 0.5).astype(jnp.float32)
+    v = jax.random.normal(ks[4], shape)
+    e_old = jax.random.normal(ks[5], shape)
+    out = kops.fused_uplink_ef(levels, scales, w, gate, v, e_old)
+    exp = ref.fused_uplink_ef(levels, scales, w, gate, v, e_old)
+    for o, x in zip(out, exp):
+        assert o.shape == x.shape
+        np.testing.assert_allclose(np.asarray(o), np.asarray(x),
+                                   rtol=3e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("r,c,rows,cols,block_c", [
+    (1, 2359296, 32, 73728, 2048),   # VGG-9 conv7: no padding at all
+    (1, 512, 32, 128, 128),          # a bias: C pads to 32·128
+    (3, 777, 96, 128, 128),          # C pads up to f·128 only
+    (64, 4096, 64, 4096, 2048),      # already a block multiple: no fold
+])
+def test_fold_pads_columns_not_rows(r, c, rows, cols, block_c):
+    from repro.kernels import uplink
+    k_ = 20
+    lv = jax.ShapeDtypeStruct((k_, r, c), jnp.int8)
+    vec = jax.ShapeDtypeStruct((k_, r), jnp.float32)
+    mat = jax.ShapeDtypeStruct((k_, r, c), jnp.float32)
+    out = {}
+
+    def fold(lv_, vec_, mat_):
+        levels, rowvecs, mats, bc = uplink._fold(
+            lv_, [vec_], [mat_], uplink.DEFAULT_BLOCK_R,
+            uplink.DEFAULT_BLOCK_C)
+        out["block_c"] = bc
+        return levels, rowvecs, mats
+
+    levels, (vec_f,), (mat_f,) = jax.eval_shape(fold, lv, vec, mat)
+    assert levels.shape == mat_f.shape == (k_, rows, cols)
+    assert vec_f.shape == (k_, rows, 1)
+    assert out["block_c"] == block_c and cols % block_c == 0
+
+
 # ----------------------------------------------------------------------
 # end-to-end: fused packed path vs the legacy unfused chain, fixed seed
 # ----------------------------------------------------------------------
