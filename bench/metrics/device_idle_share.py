@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device's op intervals) / window, from the trace."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
