@@ -518,30 +518,36 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
         # touches the psum or the outputs.
         params_shard = params
         if m > 1:
-            params = tree_all_gather(params, pspecs, MODEL_AXIS)
-            if frozen is not None:
-                frozen = tree_all_gather(frozen, fspecs, MODEL_AXIS)
-            if state is not None:
-                state = _state_model_gather(state, sspecs)
-        if frozen is None:
-            locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
-                params, batch)
-        else:
-            locals_, losses = jax.vmap(
-                lambda p, b: local_update(p, b, frozen),
-                in_axes=(None, 0))(params, batch)
+            with prof_mod.phase("fl.collective"):
+                params = tree_all_gather(params, pspecs, MODEL_AXIS)
+                if frozen is not None:
+                    frozen = tree_all_gather(frozen, fspecs, MODEL_AXIS)
+                if state is not None:
+                    state = _state_model_gather(state, sspecs)
+        with prof_mod.phase("fl.local"):
+            if frozen is None:
+                locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
+                    params, batch)
+            else:
+                locals_, losses = jax.vmap(
+                    lambda p, b: local_update(p, b, frozen),
+                    in_axes=(None, 0))(params, batch)
 
         divs = None
         if strategy.needs_divergence:
-            divs_loc = jax.vmap(lambda p: umap.divergence(p, params))(locals_)
-            divs = jax.lax.all_gather(divs_loc, ax, axis=0, tiled=True)
+            with prof_mod.phase("fl.eq3"):
+                divs_loc = jax.vmap(
+                    lambda p: umap.divergence(p, params))(locals_)
+            with prof_mod.phase("fl.collective"):
+                divs = jax.lax.all_gather(divs_loc, ax, axis=0, tiled=True)
         # selection is replicated: divs are all-gathered and global state
         # entries enter replicated (client state rows are device-local and
         # must not drive selection under a mesh — see FLStrategy docs)
-        selection = strategy.select_with_state(state, divs, key, k,
-                                               umap.num_units,
-                                               flcfg.top_n)    # (K, U), repl.
-        sel_loc = local_rows(selection, ax, kloc)
+        with prof_mod.phase("fl.eq4"):
+            selection = strategy.select_with_state(
+                state, divs, key, k, umap.num_units,
+                flcfg.top_n)                                   # (K, U), repl.
+            sel_loc = local_rows(selection, ax, kloc)
 
         # ONE fused cross-device reduction per round: the Eq. 5 numerators/
         # denominator, the loss sum, and the (additive) comm-byte totals
@@ -562,8 +568,11 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
             # the clients axis, so they ride the same fused psum below
             res_rows = (state["client"]["residual"]
                         if strategy.tracks_residuals else None)
-            parts, denom_loc, new_rows, wire = strategy.uplink_psum_parts(
-                locals_, params, umap, sel_loc, divs, data_sizes, res_rows)
+            with prof_mod.phase("fl.uplink"):
+                parts, denom_loc, new_rows, wire = \
+                    strategy.uplink_psum_parts(locals_, params, umap,
+                                               sel_loc, divs, data_sizes,
+                                               res_rows)
             if strategy.tracks_residuals:
                 state = {**state, "client": {**state["client"],
                                              "residual": new_rows}}
@@ -571,38 +580,44 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
             if strategy.transforms_upload:
                 res_rows = (state["client"]["residual"]
                             if strategy.tracks_residuals else None)
-                uploads, cand_res = jax.vmap(
-                    lambda loc, res: strategy.transform_upload(
-                        loc, params, umap, res),
-                    in_axes=(0, 0 if res_rows is not None else None),
-                )(locals_, res_rows)
-                if strategy.tracks_residuals:
-                    new_rows = jax.vmap(
-                        lambda cand, old, s: strategy.update_residual(
-                            cand, old, s, umap, params),
-                        in_axes=(0, 0, 0))(cand_res, res_rows, sel_loc)
-                    state = {**state, "client": {**state["client"],
-                                                 "residual": new_rows}}
+                with prof_mod.phase("fl.uplink"):
+                    uploads, cand_res = jax.vmap(
+                        lambda loc, res: strategy.transform_upload(
+                            loc, params, umap, res),
+                        in_axes=(0, 0 if res_rows is not None else None),
+                    )(locals_, res_rows)
+                    if strategy.tracks_residuals:
+                        new_rows = jax.vmap(
+                            lambda cand, old, s: strategy.update_residual(
+                                cand, old, s, umap, params),
+                            in_axes=(0, 0, 0))(cand_res, res_rows, sel_loc)
+                        state = {**state, "client": {**state["client"],
+                                                     "residual": new_rows}}
             else:
                 uploads = locals_
-            parts, denom_loc = strategy.psum_parts(uploads, umap, sel_loc,
-                                                   data_sizes,
-                                                   global_params=params)
+            with prof_mod.phase("fl.eq5"):
+                parts, denom_loc = strategy.psum_parts(
+                    uploads, umap, sel_loc, data_sizes,
+                    global_params=params)
         if m > 1:
-            parts = tree_shard_slice(parts, pspecs, m, MODEL_AXIS)
-            # a param-structured denominator (element-wise aggregation,
-            # e.g. FedADP's mask counts) shards with the numerators; the
-            # Eq. 5 (U,) unit denominator stays replicated
-            if jax.tree.structure(denom_loc) == jax.tree.structure(parts):
-                denom_loc = tree_shard_slice(denom_loc, pspecs, m,
-                                             MODEL_AXIS)
-        if wire is not None:
-            # charge the packed payload's actual wire bytes (bit-width
-            # vector + headers), not fp32 unit sizes
-            comm_loc = strategy.comm_profile(
-                sel_loc, umap, unit_bytes_override=wire["unit_bytes"])
-        else:
-            comm_loc = strategy.comm_profile(sel_loc, umap)
+            with prof_mod.phase("fl.collective"):
+                parts = tree_shard_slice(parts, pspecs, m, MODEL_AXIS)
+                # a param-structured denominator (element-wise
+                # aggregation, e.g. FedADP's mask counts) shards with the
+                # numerators; the Eq. 5 (U,) unit denominator stays
+                # replicated
+                if jax.tree.structure(denom_loc) == \
+                        jax.tree.structure(parts):
+                    denom_loc = tree_shard_slice(denom_loc, pspecs, m,
+                                                 MODEL_AXIS)
+        with prof_mod.phase("fl.comm"):
+            if wire is not None:
+                # charge the packed payload's actual wire bytes (bit-width
+                # vector + headers), not fp32 unit sizes
+                comm_loc = strategy.comm_profile(
+                    sel_loc, umap, unit_bytes_override=wire["unit_bytes"])
+            else:
+                comm_loc = strategy.comm_profile(sel_loc, umap)
         comm_add = {n_: v for n_, v in comm_loc.items()
                     if n_ != "savings_frac"}   # byte counts are additive
         # telemetry taps: the client-state squared-norm partials (EF
@@ -611,48 +626,57 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
         # original 3-tuple, so the compiled round is bit-identical.
         tap_client_sq = None
         if taps_on and state is not None and state.get("client"):
-            tap_client_sq = taps_mod.client_sqsums(state["client"])
-        if tap_client_sq is not None:
-            (parts, denom), loss_sum, comm, tap_client_sq = reduce_(
-                ((parts, denom_loc), losses.sum(), comm_add,
-                 tap_client_sq))
-        else:
-            (parts, denom), loss_sum, comm = reduce_(
-                ((parts, denom_loc), losses.sum(), comm_add))
-        new_params = strategy.psum_finalize(parts, denom, umap,
-                                            params_shard, params_shard)
-        comm["savings_frac"] = 1.0 - comm["uplink_total"] / \
-            comm["fedavg_uplink"]
-        # per-tier aggregation-traffic split: static topology × payload
-        # arithmetic added AFTER the reduce (deliberately not riding the
-        # psum, so the flat path's collective payload — and trajectory —
-        # stays byte-identical to the pre-tier engine). Payload = this
-        # device's Eq. 5 numerator tree (1/M slice on a 2-D mesh).
-        for n_, v in comm_mod.agg_tier_bytes(umap.total_bytes / m, d,
-                                             gs if hier else 0).items():
-            comm[n_] = jnp.float32(v)
-        loss = loss_sum / k
+            with prof_mod.phase("fl.taps"):
+                tap_client_sq = taps_mod.client_sqsums(state["client"])
+        with prof_mod.phase("fl.collective"):
+            if tap_client_sq is not None:
+                (parts, denom), loss_sum, comm, tap_client_sq = reduce_(
+                    ((parts, denom_loc), losses.sum(), comm_add,
+                     tap_client_sq))
+            else:
+                (parts, denom), loss_sum, comm = reduce_(
+                    ((parts, denom_loc), losses.sum(), comm_add))
+        with prof_mod.phase("fl.eq5"):
+            new_params = strategy.psum_finalize(parts, denom, umap,
+                                                params_shard, params_shard)
+        with prof_mod.phase("fl.comm"):
+            comm["savings_frac"] = 1.0 - comm["uplink_total"] / \
+                comm["fedavg_uplink"]
+            # per-tier aggregation-traffic split: static topology ×
+            # payload arithmetic added AFTER the reduce (deliberately not
+            # riding the psum, so the flat path's collective payload — and
+            # trajectory — stays byte-identical to the pre-tier engine).
+            # Payload = this device's Eq. 5 numerator tree (1/M slice on a
+            # 2-D mesh).
+            for n_, v in comm_mod.agg_tier_bytes(umap.total_bytes / m, d,
+                                                 gs if hier else 0).items():
+                comm[n_] = jnp.float32(v)
+            loss = loss_sum / k
         metrics = {"loss": loss, "comm": comm, "selection": selection}
         if state is not None:
             # replicated transition: selection/divs/global entries are
             # identical on every device, so the new global state is too;
             # client rows go back to this device's 1/M store-row shard
-            state = strategy.update_state(state, selection, divs, umap,
-                                          key=key)
+            with prof_mod.phase("fl.state"):
+                state = strategy.update_state(state, selection, divs, umap,
+                                              key=key)
         if taps_on:
             # replicated by construction: selection/divs/global state are
             # identical everywhere, client norms were just psum'd. The
             # non-None client_sq stops collect() from re-deriving norms
             # from the device-local rows.
-            metrics["taps"] = taps_mod.collect(
-                strategy, state, selection, divs, umap,
-                client_sq=tap_client_sq if tap_client_sq is not None else {},
-                extra=(None if wire is None else
-                       {"wire_unit_bytes": wire["unit_bytes"],
-                        "wire_bits": wire["bits"]}))
+            with prof_mod.phase("fl.taps"):
+                metrics["taps"] = taps_mod.collect(
+                    strategy, state, selection, divs, umap,
+                    client_sq=(tap_client_sq if tap_client_sq is not None
+                               else {}),
+                    extra=(None if wire is None else
+                           {"wire_unit_bytes": wire["unit_bytes"],
+                            "wire_bits": wire["bits"]}))
         if state is not None:
             if m > 1:
-                state = _state_model_slice(state, sspecs, m)
+                with prof_mod.phase("fl.collective"):
+                    state = _state_model_slice(state, sspecs, m)
             metrics["state"] = state
         return new_params, metrics
 
@@ -720,35 +744,42 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
     def round_fn(params: Pytree, batch: dict, data_sizes: jnp.ndarray,
                  key: jax.Array, state: Optional[dict] = None,
                  frozen: Optional[Pytree] = None):
-        if frozen is None:
-            locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
-                params, batch)
-        else:
-            # partitioned round: ``params`` is the trainable sub-pytree;
-            # the frozen base broadcasts into every client's local step
-            locals_, losses = jax.vmap(
-                lambda p, b: local_update(p, b, frozen),
-                in_axes=(None, 0))(params, batch)
+        with prof_mod.phase("fl.local"):
+            if frozen is None:
+                locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
+                    params, batch)
+            else:
+                # partitioned round: ``params`` is the trainable
+                # sub-pytree; the frozen base broadcasts into every
+                # client's local step
+                locals_, losses = jax.vmap(
+                    lambda p, b: local_update(p, b, frozen),
+                    in_axes=(None, 0))(params, batch)
 
         # divergence feedback (Eq. 3) is computed on the TRUE local model —
         # upload transforms (e.g. quantization) below only affect the
         # uploaded payload.
         divs = None
         if strategy.needs_divergence:
-            divs = jax.vmap(lambda p: umap.divergence(p, params))(locals_)
-        selection = strategy.select_with_state(state, divs, key, k,
-                                               umap.num_units, flcfg.top_n)
+            with prof_mod.phase("fl.eq3"):
+                divs = jax.vmap(
+                    lambda p: umap.divergence(p, params))(locals_)
+        with prof_mod.phase("fl.eq4"):
+            selection = strategy.select_with_state(
+                state, divs, key, k, umap.num_units, flcfg.top_n)
 
         wire = None
         if strategy.packed_upload:
             # packed wire-format uplink: the strategy quantizes the client
             # deltas into PackedPayload buffers and reduces them through
-            # the fused dequant+EF+accumulate kernel in one shot
+            # the fused dequant+EF+accumulate kernel in one shot (its
+            # accumulate half is scoped fl.eq5 inside the strategy)
             res_rows = (state["client"]["residual"]
                         if strategy.tracks_residuals else None)
-            new_params, new_rows, wire = strategy.uplink_round(
-                locals_, params, umap, selection, divs, data_sizes,
-                res_rows)
+            with prof_mod.phase("fl.uplink"):
+                new_params, new_rows, wire = strategy.uplink_round(
+                    locals_, params, umap, selection, divs, data_sizes,
+                    res_rows)
             if strategy.tracks_residuals:
                 state = {**state, "client": {**state["client"],
                                              "residual": new_rows}}
@@ -762,41 +793,47 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
                 # (see FLStrategy.init_state).
                 res_rows = (state["client"]["residual"]
                             if strategy.tracks_residuals else None)
-                uploads, cand_res = jax.vmap(
-                    lambda loc, res: strategy.transform_upload(
-                        loc, params, umap, res),
-                    in_axes=(0, 0 if res_rows is not None else None),
-                )(locals_, res_rows)
-                if strategy.tracks_residuals:
-                    new_rows = jax.vmap(
-                        lambda cand, old, s: strategy.update_residual(
-                            cand, old, s, umap, params),
-                        in_axes=(0, 0, 0))(cand_res, res_rows, selection)
-                    state = {**state, "client": {**state["client"],
-                                                 "residual": new_rows}}
+                with prof_mod.phase("fl.uplink"):
+                    uploads, cand_res = jax.vmap(
+                        lambda loc, res: strategy.transform_upload(
+                            loc, params, umap, res),
+                        in_axes=(0, 0 if res_rows is not None else None),
+                    )(locals_, res_rows)
+                    if strategy.tracks_residuals:
+                        new_rows = jax.vmap(
+                            lambda cand, old, s: strategy.update_residual(
+                                cand, old, s, umap, params),
+                            in_axes=(0, 0, 0))(cand_res, res_rows,
+                                               selection)
+                        state = {**state, "client": {**state["client"],
+                                                     "residual": new_rows}}
             else:
                 uploads = locals_
-            new_params = strategy.aggregate(uploads, umap, selection,
-                                            data_sizes, params)
-        if wire is not None:
-            comm = strategy.comm_profile(
-                selection, umap, unit_bytes_override=wire["unit_bytes"])
-        else:
-            comm = strategy.comm_profile(selection, umap)
-        metrics = {"loss": losses.mean(), "comm": comm,
-                   "selection": selection}
+            with prof_mod.phase("fl.eq5"):
+                new_params = strategy.aggregate(uploads, umap, selection,
+                                                data_sizes, params)
+        with prof_mod.phase("fl.comm"):
+            if wire is not None:
+                comm = strategy.comm_profile(
+                    selection, umap, unit_bytes_override=wire["unit_bytes"])
+            else:
+                comm = strategy.comm_profile(selection, umap)
+            metrics = {"loss": losses.mean(), "comm": comm,
+                       "selection": selection}
         if state is not None:
-            metrics["state"] = strategy.update_state(state, selection, divs,
-                                                     umap, key=key)
+            with prof_mod.phase("fl.state"):
+                metrics["state"] = strategy.update_state(
+                    state, selection, divs, umap, key=key)
         if taps_on:
             # client rows in the post-update_state view carry the
             # post-residual-update values (update_state preserves entries
             # it does not own), matching the mesh engine's tap timing.
-            metrics["taps"] = taps_mod.collect(
-                strategy, metrics.get("state"), selection, divs, umap,
-                extra=(None if wire is None else
-                       {"wire_unit_bytes": wire["unit_bytes"],
-                        "wire_bits": wire["bits"]}))
+            with prof_mod.phase("fl.taps"):
+                metrics["taps"] = taps_mod.collect(
+                    strategy, metrics.get("state"), selection, divs, umap,
+                    extra=(None if wire is None else
+                           {"wire_unit_bytes": wire["unit_bytes"],
+                            "wire_bits": wire["bits"]}))
         return new_params, metrics
 
     return round_fn
@@ -834,32 +871,45 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
                  frozen: Optional[Pytree] = None):
         lu = (local_update if frozen is None
               else lambda p, b: local_update(p, b, frozen))
+        # each client-loop scan is scoped fl.local as a whole (its slicing
+        # and stacking included); the Eq. 3 / Eq. 5 work inside the body
+        # carries its own, inner phase
         # ---- phase 1: divergence feedback (only if the policy needs it)
         if strategy.needs_divergence:
             def phase1(carry, batch_k):
                 local, loss = lu(params, batch_k)
-                return carry, (umap.divergence(local, params), loss)
+                with prof_mod.phase("fl.eq3"):
+                    div = umap.divergence(local, params)
+                return carry, (div, loss)
 
-            _, (divs, losses1) = jax.lax.scan(phase1, None, batch)
+            with prof_mod.phase("fl.local"):
+                _, (divs, losses1) = jax.lax.scan(phase1, None, batch)
         else:
             divs, losses1 = None, None
 
-        selection = strategy.select_with_state(state, divs, key, k,
-                                               umap.num_units, flcfg.top_n)
+        with prof_mod.phase("fl.eq4"):
+            selection = strategy.select_with_state(
+                state, divs, key, k, umap.num_units, flcfg.top_n)
 
         if strategy.eq5_weighted:
-            w, denom = agg.unit_weights(selection, data_sizes)
-            frac = w / jnp.where(denom > 0, denom, 1.0)[None, :]   # (K, U)
+            with prof_mod.phase("fl.eq5"):
+                w, denom = agg.unit_weights(selection, data_sizes)
+                frac = w / jnp.where(denom > 0, denom, 1.0)[None, :]  # (K,U)
 
             # ---- phase 2: recompute local training, stream layers in
             def phase2(acc, inp):
                 batch_k, frac_k = inp
                 local, loss = lu(params, batch_k)
-                return agg.streaming_add(acc, local, umap, frac_k), loss
+                with prof_mod.phase("fl.eq5"):
+                    acc = agg.streaming_add(acc, local, umap, frac_k)
+                return acc, loss
 
-            acc0 = agg.streaming_init(params)
-            acc, losses2 = jax.lax.scan(phase2, acc0, (batch, frac))
-            new_params = agg.streaming_finalize(acc, umap, denom, params)
+            with prof_mod.phase("fl.eq5"):
+                acc0 = agg.streaming_init(params)
+            with prof_mod.phase("fl.local"):
+                acc, losses2 = jax.lax.scan(phase2, acc0, (batch, frac))
+            with prof_mod.phase("fl.eq5"):
+                new_params = agg.streaming_finalize(acc, umap, denom, params)
         else:
             # ---- phase 2 (non-Eq.5 aggregation, e.g. FedADP): train
             # sequentially, let the scan stack the locals, and call the
@@ -867,19 +917,25 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
             def phase2_stack(carry, batch_k):
                 return carry, lu(params, batch_k)
 
-            _, (stacked, losses2) = jax.lax.scan(phase2_stack, None, batch)
-            new_params = strategy.aggregate(stacked, umap, selection,
-                                            data_sizes, params)
+            with prof_mod.phase("fl.local"):
+                _, (stacked, losses2) = jax.lax.scan(phase2_stack, None,
+                                                     batch)
+            with prof_mod.phase("fl.eq5"):
+                new_params = strategy.aggregate(stacked, umap, selection,
+                                                data_sizes, params)
 
-        comm = strategy.comm_profile(selection, umap)
-        loss = (losses1 if losses1 is not None else losses2).mean()
+        with prof_mod.phase("fl.comm"):
+            comm = strategy.comm_profile(selection, umap)
+            loss = (losses1 if losses1 is not None else losses2).mean()
         metrics = {"loss": loss, "comm": comm, "selection": selection}
         if state is not None:
-            metrics["state"] = strategy.update_state(state, selection, divs,
-                                                     umap, key=key)
+            with prof_mod.phase("fl.state"):
+                metrics["state"] = strategy.update_state(
+                    state, selection, divs, umap, key=key)
         if taps_on:
-            metrics["taps"] = taps_mod.collect(
-                strategy, metrics.get("state"), selection, divs, umap)
+            with prof_mod.phase("fl.taps"):
+                metrics["taps"] = taps_mod.collect(
+                    strategy, metrics.get("state"), selection, divs, umap)
         return new_params, metrics
 
     return round_fn
@@ -1116,64 +1172,78 @@ def run_training(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
     try:
         for t in range(start_round, start_round + rounds):
             win.round_begin(t)
-            wall0 = time.perf_counter() if sample_sys else None
-            if sampler == "jax":
-                ck, bk, key = round_keys(base_key, t)
-                # affinity-laid-out shards (num_groups > 1) switch the
-                # cohort draw to per-group sampling, matching the scan
-                # engine's trajectory on the same shards
-                clients = sample_clients_grouped(ck, flcfg.num_clients,
-                                                 flcfg.clients_per_round,
-                                                 shards.num_groups)
-                batch = shards.gather(clients, flcfg.batch_per_client, bk)
-                sizes = all_sizes_dev[clients]
-            else:
-                clients = sample_clients(rng, flcfg.num_clients,
-                                         flcfg.clients_per_round)
-                batch = fldata.round_batch(clients, flcfg.batch_per_client,
-                                           rng)
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-                sizes = jnp.asarray(all_sizes[clients])
-                key = jax.random.fold_in(host_base, t)
-                clients = jnp.asarray(clients)
-            kw = {} if frozen is None else {"frozen": frozen}
-            if state is not None:
-                st_rows = _state_round_view(state, clients)
-                params, metrics = round_fn(params, batch, sizes, key,
-                                           st_rows, **kw)
-                state = _state_scatter(state, metrics["state"], clients)
-            else:
-                params, metrics = round_fn(params, batch, sizes, key, **kw)
-            log.meter.update(metrics["comm"])
-            log.rounds.append(t)
-            loss_t = float(metrics["loss"])     # device sync
-            log.losses.append(loss_t)
-            log.uplink_mb.append(log.meter.uplink_bytes / 1e6)
-            if ledger is not None:
-                # the float() pull above synced the round, so wall_s is
-                # real compute time, not dispatch time
-                wall_s = (time.perf_counter() - wall0
-                          if wall0 is not None else None)
-                mem = (prof_mod.device_memory_peak() if sample_sys
-                       else None)
-                ledger.round(
-                    t, loss_t, jax.device_get(metrics["comm"]),
-                    log.meter.uplink_bytes,
-                    taps=(jax.device_get(metrics["taps"])
-                          if "taps" in metrics else None),
-                    selection=(metrics["selection"]
-                               if tele.full_selection else None),
-                    wall_s=wall_s, mem_peak_bytes=mem)
-            if eval_fn is not None and (t % eval_every == 0
-                                        or t == start_round + rounds - 1):
-                err = float(eval_fn(merged(params)))
-                log.test_errors.append((t, err, log.meter.uplink_bytes))
-                if ledger is not None:
-                    ledger.eval(t, err, log.meter.uplink_bytes)
-                sink.round(t, loss_t, test_error=err,
-                           uplink_bytes=log.meter.uplink_bytes)
-            elif sink.enabled and t % 10 == 0:
-                sink.round(t, loss_t)
+            with prof_mod.span("fl.host", round=t):
+                wall0 = time.perf_counter() if sample_sys else None
+                if sampler == "jax":
+                    with prof_mod.span("fl.host.sample"):
+                        ck, bk, key = round_keys(base_key, t)
+                        # affinity-laid-out shards (num_groups > 1) switch
+                        # the cohort draw to per-group sampling, matching
+                        # the scan engine's trajectory on the same shards
+                        clients = sample_clients_grouped(
+                            ck, flcfg.num_clients, flcfg.clients_per_round,
+                            shards.num_groups)
+                    with prof_mod.span("fl.host.gather"):
+                        batch = shards.gather(clients,
+                                              flcfg.batch_per_client, bk)
+                        sizes = all_sizes_dev[clients]
+                else:
+                    with prof_mod.span("fl.host.sample"):
+                        clients = sample_clients(rng, flcfg.num_clients,
+                                                 flcfg.clients_per_round)
+                    with prof_mod.span("fl.host.gather"):
+                        batch = fldata.round_batch(
+                            clients, flcfg.batch_per_client, rng)
+                        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                        sizes = jnp.asarray(all_sizes[clients])
+                        key = jax.random.fold_in(host_base, t)
+                        clients = jnp.asarray(clients)
+                with prof_mod.span("fl.host.dispatch"):
+                    kw = {} if frozen is None else {"frozen": frozen}
+                    if state is not None:
+                        st_rows = _state_round_view(state, clients)
+                        params, metrics = round_fn(params, batch, sizes, key,
+                                                   st_rows, **kw)
+                        state = _state_scatter(state, metrics["state"],
+                                               clients)
+                    else:
+                        params, metrics = round_fn(params, batch, sizes, key,
+                                                   **kw)
+                with prof_mod.span("fl.host.pull"):
+                    log.meter.update(metrics["comm"])
+                    log.rounds.append(t)
+                    loss_t = float(metrics["loss"])     # device sync
+                    log.losses.append(loss_t)
+                    log.uplink_mb.append(log.meter.uplink_bytes / 1e6)
+                do_eval = eval_fn is not None and (
+                    t % eval_every == 0 or t == start_round + rounds - 1)
+                with prof_mod.span("fl.host.log"):
+                    if ledger is not None:
+                        # the float() pull above synced the round, so
+                        # wall_s is real compute time, not dispatch time
+                        wall_s = (time.perf_counter() - wall0
+                                  if wall0 is not None else None)
+                        mem = (prof_mod.device_memory_peak() if sample_sys
+                               else None)
+                        ledger.round(
+                            t, loss_t, jax.device_get(metrics["comm"]),
+                            log.meter.uplink_bytes,
+                            taps=(jax.device_get(metrics["taps"])
+                                  if "taps" in metrics else None),
+                            selection=(metrics["selection"]
+                                       if tele.full_selection else None),
+                            wall_s=wall_s, mem_peak_bytes=mem)
+                    if not do_eval and sink.enabled and t % 10 == 0:
+                        sink.round(t, loss_t)
+                if do_eval:
+                    with prof_mod.span("fl.host.eval"):
+                        err = float(eval_fn(merged(params)))
+                        log.test_errors.append(
+                            (t, err, log.meter.uplink_bytes))
+                        if ledger is not None:
+                            ledger.eval(t, err, log.meter.uplink_bytes)
+                        sink.round(t, loss_t, test_error=err,
+                                   uplink_bytes=log.meter.uplink_bytes)
             win.round_end(t)
     finally:
         win.close()
@@ -1236,7 +1306,7 @@ def _build_block_fn(loss_fn, umap: UnitMap, flcfg: FLConfig):
 
     def one_round(carry, t, shards, all_sizes, base_key, frozen):
         params, state, acc = carry
-        ck, bk, ak = round_keys(base_key, t)
+
         # shards.num_groups is static pytree aux: affinity-laid-out shards
         # flip the cohort draw to per-group sampling at trace time (a
         # num_groups of 1 lowers to exactly sample_clients_jax).
@@ -1245,31 +1315,38 @@ def _build_block_fn(loss_fn, umap: UnitMap, flcfg: FLConfig):
                                           flcfg.clients_per_round,
                                           shards.num_groups)
 
-        if mesh is not None:
-            # run the RNG draws replicated inside shard_map: the
-            # non-partitionable threefry lowering changes values when XLA
-            # shards it (see ClientShards.gather / replicated_rng) — the
-            # participant draw gets the same treatment as the batch draw.
-            clients = replicated_rng(sample, mesh)(ck)
-        else:
-            clients = sample(ck)
-        batch = shards.gather(clients, flcfg.batch_per_client, bk, mesh=mesh)
-        sizes = all_sizes[clients]
-        if client_spec is not None:
-            batch = jax.lax.with_sharding_constraint(batch, client_spec)
-            sizes = jax.lax.with_sharding_constraint(sizes, client_spec)
+        with prof_mod.phase("fl.sample"):
+            ck, bk, ak = round_keys(base_key, t)
+            if mesh is not None:
+                # run the RNG draws replicated inside shard_map: the
+                # non-partitionable threefry lowering changes values when
+                # XLA shards it (see ClientShards.gather / replicated_rng)
+                # — the participant draw gets the same treatment as the
+                # batch draw.
+                clients = replicated_rng(sample, mesh)(ck)
+            else:
+                clients = sample(ck)
+            batch = shards.gather(clients, flcfg.batch_per_client, bk,
+                                  mesh=mesh)
+            sizes = all_sizes[clients]
+            if client_spec is not None:
+                batch = jax.lax.with_sharding_constraint(batch, client_spec)
+                sizes = jax.lax.with_sharding_constraint(sizes, client_spec)
         kw = {} if frozen is None else {"frozen": frozen}
         if state is not None:
-            st_rows = constrain_state(_state_round_view(state, clients),
-                                      params, rows=True)
+            with prof_mod.phase("fl.state"):
+                st_rows = constrain_state(_state_round_view(state, clients),
+                                          params, rows=True)
             params, metrics = round_fn(params, batch, sizes, ak, st_rows,
                                        **kw)
-            state = constrain_state(
-                _state_scatter(state, metrics.pop("state"), clients),
-                params, rows=False)
+            with prof_mod.phase("fl.state"):
+                state = constrain_state(
+                    _state_scatter(state, metrics.pop("state"), clients),
+                    params, rows=False)
         else:
             params, metrics = round_fn(params, batch, sizes, ak, **kw)
-        acc = comm_mod.comm_acc_update(acc, metrics["comm"])
+        with prof_mod.phase("fl.comm"):
+            acc = comm_mod.comm_acc_update(acc, metrics["comm"])
         per_round = {"loss": metrics["loss"],
                      "uplink_bytes": acc["uplink_bytes"]}
         # telemetry widens the stacked per-round OUTPUTS (scan ys), never
@@ -1328,41 +1405,52 @@ def run_training_scan(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
     checkpoint>`` continues a run bit-identically to one that never
     stopped (regression-tested in tests/test_state_seam.py).
     """
-    partition, frozen, pinfo = flcfg.partition, None, None
-    if partition is not None:
-        pinfo = partition_counts(partition, params)
-        params, frozen = partition.split(params)
-    umap = UnitMap.build(params)
-    shards = (fldata if isinstance(fldata, ClientShards)
-              else ClientShards.from_federated(fldata))
-    strategy = make_strategy(flcfg)
-    run_block = _cached("block", loss_fn, umap, flcfg,
-                        lambda: _build_block_fn(loss_fn, umap, flcfg))
-    if flcfg.mesh is not None:
-        # replicated over 'clients', FSDP-sharded over 'model' (2-D mesh);
-        # the frozen base follows the same placement policy
-        params = jax.device_put(
-            params, to_named(fl_param_specs(params, flcfg.mesh), flcfg.mesh))
-        if frozen is not None:
-            frozen = jax.device_put(
-                frozen,
-                to_named(fl_param_specs(frozen, flcfg.mesh), flcfg.mesh))
-        shards = shards.place(flcfg.mesh,
-                              shard_samples=flcfg.shard_samples)
-    merged = ((lambda p: p) if partition is None
-              else (lambda p: partition.merge(p, frozen)))
-    # run_block donates its carry; copy once so the caller's param and
-    # resumed-state buffers survive the first block
-    params = jax.tree.map(jnp.copy, params)
-    if server_state is not None:
-        state0 = jax.tree.map(jnp.copy, (
-            _place_state(server_state, params, strategy, flcfg.mesh)
-            if flcfg.mesh is not None else server_state))
-    else:
-        state0 = strategy.init_state(params, flcfg.num_clients, flcfg.mesh)
-    carry = (params, state0, comm_mod.comm_acc_init())
-    all_sizes = shards.data_sizes()
-    base_key = jax.random.PRNGKey(seed)
+    with prof_mod.span("fl.scan", start_round=start_round, rounds=rounds):
+        return _run_training_scan(params, loss_fn, fldata, flcfg, rounds,
+                                  eval_fn, eval_every, seed, verbose,
+                                  start_round, server_state)
+
+
+def _run_training_scan(params, loss_fn, fldata, flcfg, rounds, eval_fn,
+                       eval_every, seed, verbose, start_round, server_state):
+    """:func:`run_training_scan`'s body, one host span per phase."""
+    with prof_mod.span("fl.scan.prepare"):
+        partition, frozen, pinfo = flcfg.partition, None, None
+        if partition is not None:
+            pinfo = partition_counts(partition, params)
+            params, frozen = partition.split(params)
+        umap = UnitMap.build(params)
+        shards = (fldata if isinstance(fldata, ClientShards)
+                  else ClientShards.from_federated(fldata))
+        strategy = make_strategy(flcfg)
+        run_block = _cached("block", loss_fn, umap, flcfg,
+                            lambda: _build_block_fn(loss_fn, umap, flcfg))
+        if flcfg.mesh is not None:
+            # replicated over 'clients', FSDP-sharded over 'model' (2-D
+            # mesh); the frozen base follows the same placement policy
+            params = jax.device_put(
+                params,
+                to_named(fl_param_specs(params, flcfg.mesh), flcfg.mesh))
+            if frozen is not None:
+                frozen = jax.device_put(
+                    frozen,
+                    to_named(fl_param_specs(frozen, flcfg.mesh), flcfg.mesh))
+            shards = shards.place(flcfg.mesh,
+                                  shard_samples=flcfg.shard_samples)
+        merged = ((lambda p: p) if partition is None
+                  else (lambda p: partition.merge(p, frozen)))
+    with prof_mod.span("fl.scan.copy_carry"):
+        # run_block donates its carry; copy once so the caller's param and
+        # resumed-state buffers survive the first block
+        params = jax.tree.map(jnp.copy, params)
+        if server_state is not None:
+            state0 = jax.tree.map(jnp.copy, (
+                _place_state(server_state, params, strategy, flcfg.mesh)
+                if flcfg.mesh is not None else server_state))
+        else:
+            state0 = strategy.init_state(params, flcfg.num_clients,
+                                         flcfg.mesh)
+        carry = (params, state0, comm_mod.comm_acc_init())
     log = TrainLog()
     tele = flcfg.telemetry
     sink = ProgressSink.for_run(tele, verbose)
@@ -1375,61 +1463,71 @@ def run_training_scan(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
             start_round=start_round, rounds=rounds, run_id=tele.run_id,
             partition_info=pinfo))
     run_kw = {} if frozen is None else {"frozen": frozen}
+    all_sizes = base_key = None
     t0 = 0
     try:
         for cut in _eval_cuts(rounds, eval_every, eval_fn is not None):
             num = cut - t0
             win.block_begin(start_round + t0, start_round + cut)
             wall0 = time.perf_counter() if sample_sys else None
-            carry, per_round = run_block(carry, shards, all_sizes, base_key,
-                                         jnp.int32(start_round + t0), num,
-                                         **run_kw)
-            losses = np.asarray(per_round["loss"])
-            uplink = np.asarray(per_round["uplink_bytes"])
+            with prof_mod.span("fl.scan.dispatch"):
+                if base_key is None:
+                    all_sizes = shards.data_sizes()
+                    base_key = jax.random.PRNGKey(seed)
+                carry, per_round = run_block(
+                    carry, shards, all_sizes, base_key,
+                    jnp.int32(start_round + t0), num, **run_kw)
+            with prof_mod.span("fl.scan.pull"):
+                losses = np.asarray(per_round["loss"])
+                uplink = np.asarray(per_round["uplink_bytes"])
             # the np.asarray pulls above synced the block, so block wall
             # time is real compute; per-round wall is the amortised share
             block_wall = (time.perf_counter() - wall0
                           if wall0 is not None else None)
-            log.rounds.extend(range(start_round + t0, start_round + cut))
-            log.losses.extend(float(l) for l in losses)
-            log.uplink_mb.extend(float(u) / 1e6 for u in uplink)
-            if ledger is not None:
-                wall_each = (block_wall / num
-                             if block_wall is not None else None)
-                mem = (prof_mod.device_memory_peak() if sample_sys
-                       else None)
-                comm_stack = jax.device_get(per_round["comm"])
-                taps_stack = (jax.device_get(per_round["taps"])
-                              if "taps" in per_round else None)
-                sel_stack = (np.asarray(per_round["selection"])
-                             if "selection" in per_round else None)
-                for i in range(num):
-                    ledger.round(
-                        start_round + t0 + i, losses[i],
-                        jax.tree.map(lambda a, i=i: a[i], comm_stack),
-                        uplink[i],
-                        taps=(jax.tree.map(lambda a, i=i: a[i], taps_stack)
-                              if taps_stack is not None else None),
-                        selection=(sel_stack[i] if sel_stack is not None
-                                   else None),
-                        wall_s=wall_each, mem_peak_bytes=mem)
             t_last = start_round + cut - 1
-            if eval_fn is not None:
-                err = float(eval_fn(merged(carry[0])))
-                log.test_errors.append((t_last, err, float(uplink[-1])))
+            with prof_mod.span("fl.scan.log"):
+                log.rounds.extend(range(start_round + t0, start_round + cut))
+                log.losses.extend(float(l) for l in losses)
+                log.uplink_mb.extend(float(u) / 1e6 for u in uplink)
                 if ledger is not None:
-                    ledger.eval(t_last, err, float(uplink[-1]))
-                sink.round(t_last, float(losses[-1]), test_error=err,
-                           uplink_bytes=float(uplink[-1]))
-            elif sink.enabled:
-                sink.round(t_last, float(losses[-1]))
+                    wall_each = (block_wall / num
+                                 if block_wall is not None else None)
+                    mem = (prof_mod.device_memory_peak() if sample_sys
+                           else None)
+                    comm_stack = jax.device_get(per_round["comm"])
+                    taps_stack = (jax.device_get(per_round["taps"])
+                                  if "taps" in per_round else None)
+                    sel_stack = (np.asarray(per_round["selection"])
+                                 if "selection" in per_round else None)
+                    for i in range(num):
+                        ledger.round(
+                            start_round + t0 + i, losses[i],
+                            jax.tree.map(lambda a, i=i: a[i], comm_stack),
+                            uplink[i],
+                            taps=(jax.tree.map(lambda a, i=i: a[i],
+                                               taps_stack)
+                                  if taps_stack is not None else None),
+                            selection=(sel_stack[i] if sel_stack is not None
+                                       else None),
+                            wall_s=wall_each, mem_peak_bytes=mem)
+                if eval_fn is None and sink.enabled:
+                    sink.round(t_last, float(losses[-1]))
+            if eval_fn is not None:
+                with prof_mod.span("fl.scan.eval"):
+                    err = float(eval_fn(merged(carry[0])))
+                    log.test_errors.append((t_last, err, float(uplink[-1])))
+                    if ledger is not None:
+                        ledger.eval(t_last, err, float(uplink[-1]))
+                    sink.round(t_last, float(losses[-1]), test_error=err,
+                               uplink_bytes=float(uplink[-1]))
             win.block_end(start_round + cut)
             t0 = cut
     finally:
         win.close()
         if ledger is not None:
             ledger.close()
-    params, final_state, acc = carry
-    log.meter = comm_mod.CommMeter.from_accumulator(acc)
-    log.final_state = final_state
-    return merged(params), log
+    with prof_mod.span("fl.scan.finish"):
+        params, final_state, acc = carry
+        log.meter = comm_mod.CommMeter.from_accumulator(acc)
+        log.final_state = final_state
+        return merged(params), log

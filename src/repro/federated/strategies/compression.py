@@ -37,6 +37,7 @@ from repro.core.units import tree_sub
 from repro.core.wire import CompressionConfig
 from repro.federated.strategies.base import FLStrategy
 from repro.kernels import ops as kops
+from repro.telemetry import profiling as prof_mod
 
 
 class QuantizedUpload(FLStrategy):
@@ -137,7 +138,8 @@ class QuantizedUpload(FLStrategy):
         comp = self.comp
         k = sel_rows.shape[0]
         bits = comp.bits_vector(umap, divs)                  # (U,) f32
-        w, denom = agg.unit_weights(sel_rows, data_sizes)    # (K,U), (U,)
+        with prof_mod.phase("fl.eq5"):
+            w, denom = agg.unit_weights(sel_rows, data_sizes)  # (K,U), (U,)
         ef = res_rows is not None
 
         def quantize_one(loc, res):
@@ -168,50 +170,53 @@ class QuantizedUpload(FLStrategy):
             scales_k, bits, storage_bits=comp.storage_bits)
         levels_k = wire_mod.unpack_levels(payload, v_k)
 
-        num_parts = {}
-        res_parts = {} if ef else None
-        for key, (off, n) in umap.spans.items():
-            w_seg = jax.lax.dynamic_slice(w, (0, off), (k, n))
-            s_seg = jax.lax.dynamic_slice(scales_k, (0, off), (k, n))
-            g_seg = jax.lax.dynamic_slice(sel_rows, (0, off), (k, n))
-            d_seg = jax.lax.dynamic_slice(denom, (off,), (n,))
+        # the quantize/pack half above runs in the engine's fl.uplink
+        # phase; the fused dequant+EF+accumulate half below is Eq. 5
+        with prof_mod.phase("fl.eq5"):
+            num_parts = {}
+            res_parts = {} if ef else None
+            for key, (off, n) in umap.spans.items():
+                w_seg = jax.lax.dynamic_slice(w, (0, off), (k, n))
+                s_seg = jax.lax.dynamic_slice(scales_k, (0, off), (k, n))
+                g_seg = jax.lax.dynamic_slice(sel_rows, (0, off), (k, n))
+                d_seg = jax.lax.dynamic_slice(denom, (off,), (n,))
 
-            def reduce_leaf(lv, vv, ee, g_leaf):
-                # lv/vv/ee: (K, n, ...) stacked or (K, ...); flatten the
-                # trailing dims so each unit is one kernel row
-                lv2 = lv.reshape(k, n, -1)
-                v2 = vv.reshape(k, n, -1)
-                g2 = g_leaf.astype(jnp.float32).reshape(n, -1)
-                if ee is not None:
-                    e2 = ee.reshape(k, n, -1)
-                    num2, res2 = kops.fused_uplink_ef(lv2, s_seg, w_seg,
-                                                      g_seg, v2, e2)
+                def reduce_leaf(lv, vv, ee, g_leaf):
+                    # lv/vv/ee: (K, n, ...) stacked or (K, ...); flatten the
+                    # trailing dims so each unit is one kernel row
+                    lv2 = lv.reshape(k, n, -1)
+                    v2 = vv.reshape(k, n, -1)
+                    g2 = g_leaf.astype(jnp.float32).reshape(n, -1)
+                    if ee is not None:
+                        e2 = ee.reshape(k, n, -1)
+                        num2, res2 = kops.fused_uplink_ef(lv2, s_seg, w_seg,
+                                                          g_seg, v2, e2)
+                    else:
+                        num2 = kops.fused_uplink(lv2, s_seg, w_seg)
+                        res2 = None
+                    # Σ_k w·Θ̂ = denom·Ĝ + Σ_k w·recon (the kernel term)
+                    num2 = num2 + d_seg[:, None] * g2
+                    num = num2.reshape(g_leaf.shape).astype(jnp.float32)
+                    res = (None if res2 is None
+                           else res2.reshape((k,) + g_leaf.shape))
+                    return num, res
+
+                glob = global_params[key]
+                if ef:
+                    out = jax.tree.map(reduce_leaf, levels_k[key], v_k[key],
+                                       res_rows[key], glob)
                 else:
-                    num2 = kops.fused_uplink(lv2, s_seg, w_seg)
-                    res2 = None
-                # Σ_k w·Θ̂ = denom·Ĝ + Σ_k w·recon (the kernel term)
-                num2 = num2 + d_seg[:, None] * g2
-                num = num2.reshape(g_leaf.shape).astype(jnp.float32)
-                res = (None if res2 is None
-                       else res2.reshape((k,) + g_leaf.shape))
-                return num, res
-
-            glob = global_params[key]
-            if ef:
-                out = jax.tree.map(reduce_leaf, levels_k[key], v_k[key],
-                                   res_rows[key], glob)
-            else:
-                out = jax.tree.map(
-                    lambda lv, vv, g_leaf: reduce_leaf(lv, vv, None,
-                                                       g_leaf),
-                    levels_k[key], v_k[key], glob)
-            num_parts[key] = jax.tree.map(lambda o: o[0], out,
-                                          is_leaf=lambda o: isinstance(
-                                              o, tuple))
-            if ef:
-                res_parts[key] = jax.tree.map(lambda o: o[1], out,
+                    out = jax.tree.map(
+                        lambda lv, vv, g_leaf: reduce_leaf(lv, vv, None,
+                                                           g_leaf),
+                        levels_k[key], v_k[key], glob)
+                num_parts[key] = jax.tree.map(lambda o: o[0], out,
                                               is_leaf=lambda o: isinstance(
                                                   o, tuple))
+                if ef:
+                    res_parts[key] = jax.tree.map(lambda o: o[1], out,
+                                                  is_leaf=lambda o: isinstance(
+                                                      o, tuple))
 
         wire = {"unit_bytes": payload.unit_wire_bytes(umap),
                 "bits": bits, "nbytes": payload.nbytes}
@@ -222,8 +227,9 @@ class QuantizedUpload(FLStrategy):
         parts, denom, new_rows, wire = self._packed_reduce(
             locals_, global_params, umap, selection, divs, data_sizes,
             res_rows)
-        new_params = self.psum_finalize(parts, denom, umap, global_params,
-                                        global_params)
+        with prof_mod.phase("fl.eq5"):
+            new_params = self.psum_finalize(parts, denom, umap,
+                                            global_params, global_params)
         return new_params, new_rows, wire
 
     def uplink_psum_parts(self, locals_, global_params, umap, sel_loc,

@@ -1,14 +1,42 @@
-"""Profiling hooks: trace windows, retrace counters, system sampling.
+"""Profiling hooks: trace windows, driver spans, round phases, retrace
+counters, system sampling.
 
-Three independent facilities the round drivers wire in when telemetry is
-configured:
+Independent facilities the round drivers wire in:
 
 - :class:`ProfileWindow` — a ``jax.profiler`` trace over a configurable
   absolute-round range (``TelemetryConfig.profile_rounds``). The host
   driver opens/closes it exactly at the window bounds; the scan driver
   snaps to eval-block boundaries (a jitted ``lax.scan`` cannot be split
   mid-block). Profiler failures degrade to a one-time warning — tracing
-  is best-effort observability, never a correctness dependency.
+  is best-effort observability, never a correctness dependency. Its
+  traces hold the driver spans and round phases below.
+- :func:`span` / :func:`phase` — the names a trace carries, always on
+  and with no option. A span is a host ``TraceAnnotation``: inert unless
+  a profiler trace is open, then recorded on the device ops' clock, so
+  each device-idle gap falls inside a named span. The scan driver
+  (``run_training_scan``) opens ``fl.scan`` per call (stats
+  ``start_round``, ``rounds``) around ``fl.scan.prepare`` (unit map,
+  strategy, engine-cache lookup, mesh placement),
+  ``fl.scan.copy_carry`` (copies of params and resumed state before
+  donation, or ``init_state``; the comm accumulator), and per eval
+  block ``fl.scan.dispatch`` (sizes, base key, the block call: a compile
+  shows here), ``fl.scan.pull``, ``fl.scan.log`` and, with an eval
+  function, ``fl.scan.eval``; then ``fl.scan.finish``. The host driver
+  (``run_training``) opens ``fl.host`` per round (stat ``round``) around
+  ``fl.host.sample``, ``.gather``, ``.dispatch``, ``.pull``, ``.log``
+  and, on eval rounds, ``.eval``. ProfileWindow's windows start and
+  stop outside these spans, so a window holds whole rounds or blocks.
+  A phase is a ``jax.named_scope`` on a part of the compiled round: it
+  lands in each op's ``op_name`` metadata and changes nothing else.
+  ``fl.sample`` (cohort draw, batch gather, sizes), ``fl.local`` (local
+  training; in scan mode the client loops), ``fl.eq3`` (divergence),
+  ``fl.eq4`` (selection), ``fl.uplink`` (upload transform, quantize/pack,
+  EF residual update), ``fl.eq5`` (aggregation, the fused
+  dequant+EF+accumulate), ``fl.state`` (state rows, scatter, strategy
+  transition), ``fl.comm`` (comm accounting, round loss), ``fl.taps``
+  and, on a mesh, ``fl.collective`` (all-gathers, the fused psum). Where
+  phases nest, the innermost owns an op. ``bench/spantrace.py`` reads
+  both.
 - **engine-cache retrace counters** — ``repro.federated.server``'s
   compiled-callable cache reports every build/hit here, so "did this
   config recompile?" is a queryable fact instead of a wall-clock guess:
@@ -23,6 +51,24 @@ from __future__ import annotations
 import collections
 import sys
 from typing import Optional
+
+# ----------------------------------------------------------------------
+# Driver spans and round phases
+# ----------------------------------------------------------------------
+def span(name: str, **stats):
+    """Host span ``name`` with integer/string ``stats`` (a
+    ``jax.profiler.TraceAnnotation``; about a microsecond when no trace
+    is open)."""
+    import jax
+    return jax.profiler.TraceAnnotation(name, **stats)
+
+
+def phase(name: str):
+    """Phase ``name`` of the compiled round (a ``jax.named_scope``): it
+    names the ops traced under it and changes nothing they compute."""
+    import jax
+    return jax.named_scope(name)
+
 
 # ----------------------------------------------------------------------
 # Engine-cache retrace counters
