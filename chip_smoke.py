@@ -14,8 +14,8 @@ and the device-memory peak:
 
 1. device: a TPU must be present; anything else exits 1 with no result.
 2. kernels: the four Pallas kernels at VGG-9 leaf shapes (K=20 for the
-   uplink), against ``kernels/ref.py``, each compiled program holding a
-   ``tpu_custom_call``.
+   uplink and for Eq. 3 as the vmap round calls it), against
+   ``kernels/ref.py``, each compiled program holding a ``tpu_custom_call``.
 3. engine: ``run_training_scan`` for 3 rounds with an eval after each,
    cold and then warm, against ``run_training(sampler="jax")``.
 4. sequential clients: ``mode="scan"`` (the streaming Eq. 5 accumulate)
@@ -247,6 +247,8 @@ def phase_kernels(smoke: Smoke) -> str:
             ("sqdiff_rowsum",
              lambda a, b: kops.sqdiff_rowsum(row(a), row(b)),
              lambda a, b: ref.sqdiff_rowsum(row(a), row(b)), (a, b)),
+            # Eq. 3 as the vmap round calls it: K stacked locals, one call
+            ("sqdiff_units", kops.sqdiff_units, ref.sqdiff_units, (v, b)),
             ("masked_accumulate",
              lambda a, b, w: kops.masked_accumulate(row(a), row(b), w),
              lambda a, b, w: ref.masked_accumulate(row(a), row(b), w),
@@ -263,7 +265,9 @@ def phase_kernels(smoke: Smoke) -> str:
              (levels, scales, wk, gate, v, e)),
         ]
         for kname, fn, ref_fn, args in cases:
+            t0 = time.perf_counter()
             compiled = jax.jit(fn).lower(*args).compile()
+            compile_s = time.perf_counter() - t0
             if KERNEL_MARK not in compiled.as_text():
                 raise RuntimeError(f"{kname} {name}: compiled program has "
                                    f"no {KERNEL_MARK}")
@@ -275,12 +279,14 @@ def phase_kernels(smoke: Smoke) -> str:
                       for o, x in zip(jax.tree.leaves(out),
                                       jax.tree.leaves(exp)))
             _say(f"  {kname:17s} {name:8s} {str(shape):18s} "
-                 f"rel_err={err:.2e} tpu_custom_call=yes")
+                 f"rel_err={err:.2e} compile_s={compile_s:.1f} "
+                 f"tpu_custom_call=yes")
             if not err <= KERNEL_TOL:
                 raise RuntimeError(f"{kname} {name}: relative error "
                                    f"{err:.2e} exceeds {KERNEL_TOL:.0e}")
             worst = max(worst, err)
-    return f"kernels=4 leaves={len(leaves)} worst_rel_err={worst:.2e}"
+    return (f"cases={len(cases)} leaves={len(leaves)} "
+            f"worst_rel_err={worst:.2e}")
 
 
 # ======================================================================

@@ -103,35 +103,44 @@ class UnitMap:
         return jnp.asarray(self.unit_bytes, dtype=jnp.float32)
 
     # ------------------------------------------------------------------
-    def sq_divergence(self, params: Pytree, ref: Pytree,
-                      sqdiff_rowsum: Callable | None = None) -> jnp.ndarray:
-        """Per-unit sum of squared differences, shape ``(U,)`` fp32.
+    def divergence_batched(self, locals_: Pytree,
+                           ref: Pytree) -> jnp.ndarray:
+        """Eq. 3 for K stacked local models (leading client axis on every
+        leaf): per-unit L2 norm of (local − ref), ``(K, U)`` fp32.
 
-        ``sqdiff_rowsum(a2d, b2d) -> (rows,)`` may be supplied to route the
-        row-reduction through the Pallas kernel; defaults to pure jnp.
-        """
+        Each leaf is one kernel call for all K clients
+        (``kernels.ops.sqdiff_units``), which reads ``ref`` once."""
         from repro.kernels import ops as kops  # local import; no cycle
-        rowsum = sqdiff_rowsum or kops.sqdiff_rowsum
-        out = jnp.zeros((self.num_units,), dtype=jnp.float32)
-        for key, (off, n) in self.spans.items():
-            a_leaves = jax.tree.leaves(params[key])
-            b_leaves = jax.tree.leaves(ref[key])
-            if n > 1:
-                acc = jnp.zeros((n,), dtype=jnp.float32)
-                for a, b in zip(a_leaves, b_leaves):
-                    acc = acc + rowsum(a.reshape(n, -1), b.reshape(n, -1))
-                out = jax.lax.dynamic_update_slice(out, acc, (off,))
-            else:
-                acc = jnp.zeros((1,), dtype=jnp.float32)
-                for a, b in zip(a_leaves, b_leaves):
-                    acc = acc + rowsum(a.reshape(1, -1), b.reshape(1, -1))
-                out = jax.lax.dynamic_update_slice(out, acc, (off,))
-        return out
+        parts = []
+        for key, (_, n) in sorted(self.spans.items(), key=lambda s: s[1][0]):
+            parts.append(sum(kops.sqdiff_units(a, b, n) for a, b in zip(
+                jax.tree.leaves(locals_[key]), jax.tree.leaves(ref[key]))))
+        return jnp.sqrt(jnp.concatenate(parts, axis=1))
 
-    def divergence(self, params: Pytree, ref: Pytree,
-                   sqdiff_rowsum: Callable | None = None) -> jnp.ndarray:
-        """Eq. 3: per-unit L2 norm of (params − ref), shape ``(U,)``."""
-        return jnp.sqrt(self.sq_divergence(params, ref, sqdiff_rowsum))
+    def divergence(self, params: Pytree, ref: Pytree) -> jnp.ndarray:
+        """Eq. 3: per-unit L2 norm of (params − ref), shape ``(U,)``: one
+        client of :meth:`divergence_batched`. Under ``jax.vmap`` the mapped
+        axis becomes the kernel's client axis, one call per leaf."""
+        one = jax.tree.map(lambda l: l[None], params)
+        return self.divergence_batched(one, ref)[0]
+
+    def divergence_plan(self, params: Pytree) -> list:
+        """Eq. 3's static plan, from shapes: ``(leaf path, LeafView)`` per
+        leaf in the order the divergence reads them. The view says whether
+        the kernel reads the leaf in place or folded, and ``view.nbytes``
+        the bytes one client's leaf reads."""
+        from repro.kernels.divergence import leaf_view
+        return [(key + jax.tree_util.keystr(path),
+                 leaf_view(leaf.shape, leaf.dtype, n))
+                for key, (_, n) in self.spans.items()
+                for path, leaf in jax.tree_util.tree_leaves_with_path(
+                    params[key])]
+
+    def in_place_share(self, params: Pytree) -> float:
+        """Share of Eq. 3's bytes that the kernel reads in place."""
+        plan = self.divergence_plan(params)
+        total = sum(v.nbytes for _, v in plan)
+        return sum(v.nbytes for _, v in plan if v.in_place) / max(total, 1)
 
     # ------------------------------------------------------------------
     def scale_by_unit(self, tree: Pytree, per_unit: jnp.ndarray) -> Pytree:

@@ -536,8 +536,7 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
         divs = None
         if strategy.needs_divergence:
             with prof_mod.phase("fl.eq3"):
-                divs_loc = jax.vmap(
-                    lambda p: umap.divergence(p, params))(locals_)
+                divs_loc = umap.divergence_batched(locals_, params)
             with prof_mod.phase("fl.collective"):
                 divs = jax.lax.all_gather(divs_loc, ax, axis=0, tiled=True)
         # selection is replicated: divs are all-gathered and global state
@@ -762,8 +761,7 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
         divs = None
         if strategy.needs_divergence:
             with prof_mod.phase("fl.eq3"):
-                divs = jax.vmap(
-                    lambda p: umap.divergence(p, params))(locals_)
+                divs = umap.divergence_batched(locals_, params)
         with prof_mod.phase("fl.eq4"):
             selection = strategy.select_with_state(
                 state, divs, key, k, umap.num_units, flcfg.top_n)
@@ -1011,8 +1009,8 @@ def _cached(kind: str, loss_fn, umap: UnitMap, flcfg: FLConfig, build):
 # ======================================================================
 # Multi-round drivers
 # ======================================================================
-def _run_meta(flcfg: FLConfig, *, driver: str, umap: UnitMap, seed: int,
-              sampler: str, start_round: int, rounds: int,
+def _run_meta(flcfg: FLConfig, *, driver: str, umap: UnitMap, params: Pytree,
+              seed: int, sampler: str, start_round: int, rounds: int,
               run_id: str, partition_info: Optional[dict] = None) -> dict:
     """Ledger run-header metadata: everything a consumer needs to label a
     segment without rebuilding the model (notably the layer-unit names,
@@ -1043,6 +1041,8 @@ def _run_meta(flcfg: FLConfig, *, driver: str, umap: UnitMap, seed: int,
                              "fused": flcfg.compression.fused}),
             "mesh": (dict(mesh.shape) if mesh is not None else None),
             "units": list(umap.names),
+            # share of Eq. 3's bytes the divergence kernel reads in place
+            "eq3_in_place_share": umap.in_place_share(params),
             "unit_bytes": [float(b) for b in np.asarray(umap.unit_bytes)]}
 
 
@@ -1123,9 +1123,9 @@ def run_training(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
     ledger = None
     if tele is not None and tele.wants_ledger:
         ledger = RoundLedger(tele.ledger_path, meta=_run_meta(
-            flcfg, driver="host", umap=umap, seed=seed, sampler=sampler,
-            start_round=start_round, rounds=rounds, run_id=tele.run_id,
-            partition_info=pinfo))
+            flcfg, driver="host", umap=umap, params=params, seed=seed,
+            sampler=sampler, start_round=start_round, rounds=rounds,
+            run_id=tele.run_id, partition_info=pinfo))
     if flcfg.mesh is not None:
         # place the global model over the mesh: replicated across 'clients'
         # so the sharded round starts from device-local copies everywhere,
@@ -1459,9 +1459,9 @@ def _run_training_scan(params, loss_fn, fldata, flcfg, rounds, eval_fn,
     ledger = None
     if tele is not None and tele.wants_ledger:
         ledger = RoundLedger(tele.ledger_path, meta=_run_meta(
-            flcfg, driver="scan", umap=umap, seed=seed, sampler="jax",
-            start_round=start_round, rounds=rounds, run_id=tele.run_id,
-            partition_info=pinfo))
+            flcfg, driver="scan", umap=umap, params=params, seed=seed,
+            sampler="jax", start_round=start_round, rounds=rounds,
+            run_id=tele.run_id, partition_info=pinfo))
     run_kw = {} if frozen is None else {"frozen": frozen}
     all_sizes = base_key = None
     t0 = 0

@@ -1,7 +1,8 @@
 """Pallas TPU kernels.
 
 FedLDF hot spots:
-- divergence.py : per-row Σ(a−b)² (Eq. 3 inner reduction), VMEM-tiled.
+- divergence.py : per-unit Σ(a−b)² of one leaf for K clients (Eq. 3),
+                  each leaf read once in its own layout.
 - aggregate.py  : fused acc += w[r]·x (Eq. 5 accumulation).
 - uplink.py     : fused packed-uplink dequant + EF update + Eq. 5
                   accumulate over int8 wire buffers (core/wire.py).

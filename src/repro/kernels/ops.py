@@ -4,7 +4,7 @@ On TPU the Pallas kernels run compiled; on CPU (this container) the pure-jnp
 reference is both the oracle and the fast path (interpret-mode Pallas
 executes the kernel body in Python and is only used for validation).
 
-The kernel entry points (``sqdiff_rowsum``, ``masked_accumulate``,
+The kernel entry points (``sqdiff_units``, ``masked_accumulate``,
 ``flash_attention``) default to ``interpret=None``, which resolves through
 :func:`_interpret` here — so TPU callers get compiled Pallas without opting
 in, and CPU callers get interpret mode.
@@ -33,6 +33,15 @@ def _use_pallas() -> bool:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def sqdiff_units(a: jnp.ndarray, b: jnp.ndarray, rows: int = 1) -> jnp.ndarray:
+    """(K,) + leaf, leaf of ``rows`` unit rows -> (K, rows) float32
+    per-unit Σ(a−b)² (Eq. 3 for one leaf and K clients)."""
+    if _use_pallas():
+        return _divergence.sqdiff_units(a, b, rows=rows,
+                                        interpret=_interpret())
+    return _ref.sqdiff_units(a, b, rows)
 
 
 def sqdiff_rowsum(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -18,6 +18,17 @@ def sqdiff_rowsum(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(d * d, axis=1)
 
 
+def sqdiff_units(a: jnp.ndarray, b: jnp.ndarray, rows: int = 1) -> jnp.ndarray:
+    """Per-unit sum of squared differences for K clients of one leaf.
+
+    a: (K,) + b.shape; b holds ``rows`` unit rows (its leading dimension
+    when ``rows > 1``). Returns (K, rows) float32.
+    """
+    d = (a.astype(jnp.float32).reshape(a.shape[0], rows, -1)
+         - b.astype(jnp.float32).reshape(1, rows, -1))
+    return jnp.sum(d * d, axis=2)
+
+
 def masked_accumulate(acc: jnp.ndarray, x: jnp.ndarray,
                       w: jnp.ndarray) -> jnp.ndarray:
     """acc + w[:, None] * x — the Eq. 5 per-layer weighted accumulation.

@@ -80,6 +80,37 @@ class TestUnitMap:
         d1 = jax.jit(umap.divergence)(p, r)
         np.testing.assert_allclose(d1, umap.divergence(p, r), rtol=1e-6)
 
+    @pytest.mark.parametrize("pallas", [False, True], ids=["jnp", "pallas"])
+    @pytest.mark.parametrize("path", ["vmap", "scan", "jax_vmap"])
+    def test_divergence_paths_match_numpy(self, monkeypatch, path, pallas):
+        """Eq. 3 per unit as each round calls it: the vmap round scores
+        the stacked locals in one call per leaf, the scan round one client
+        at a time, and an outer ``jax.vmap`` batches the per-client call;
+        on the jnp path and through the Pallas kernel (interpret mode),
+        with in-place and folded leaves alike."""
+        monkeypatch.setenv("REPRO_FORCE_PALLAS", "1" if pallas else "0")
+        k = 3
+        keys = jax.random.split(jax.random.PRNGKey(5), 4)
+        g = dict(_params(0), conv={
+            "w": jax.random.normal(keys[0], (3, 3, 8, 128)),
+            "b": jax.random.normal(keys[1], (128,))})
+        g["blocks"]["lora_b"] = jax.random.normal(keys[2], (3, 8, 256))
+        locals_ = jax.tree.map(
+            lambda l: l + jax.random.normal(keys[3], (k,) + l.shape),
+            g)
+        umap = UnitMap.build(g)
+        exp = np.stack([_np_divergence(jax.tree.map(lambda l: l[i],
+                                                    locals_), g, umap)
+                        for i in range(k)])
+        if path == "vmap":
+            got = umap.divergence_batched(locals_, g)
+        elif path == "scan":
+            got = jnp.stack([umap.divergence(
+                jax.tree.map(lambda l: l[i], locals_), g) for i in range(k)])
+        else:
+            got = jax.vmap(lambda p: umap.divergence(p, g))(locals_)
+        np.testing.assert_allclose(got, exp, rtol=1e-5)
+
 
 # ----------------------------------------------------------------------
 class TestSelection:

@@ -47,13 +47,117 @@ def test_masked_accumulate_matches_ref(shape, dtype):
 
 @pytest.mark.parametrize("block_r,block_c", [(8, 128), (8, 2048), (16, 512)])
 def test_sqdiff_block_shape_invariance(block_r, block_c):
-    """Result must not depend on the BlockSpec tiling."""
-    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    """Result must not depend on the block size: ``block_bytes`` of one
+    (block_r, block_c) f32 block, from many small steps to a whole unit,
+    with ragged last blocks where the rows do not divide."""
+    block_bytes = block_r * block_c * 4
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
     a = jax.random.normal(k1, (21, 3000))
     b = jax.random.normal(k2, (21, 3000))
-    out = kd.sqdiff_rowsum(a, b, block_r=block_r, block_c=block_c,
-                           interpret=True)
+    out = kd.sqdiff_rowsum(a, b, block_bytes=block_bytes, interpret=True)
     np.testing.assert_allclose(out, ref.sqdiff_rowsum(a, b), rtol=1e-5)
+    # in place, 216 rows of 128: 27 exact, 2 or 4 ragged blocks
+    la = jax.random.normal(k3, (3, 3, 3, 24, 128))
+    lb = jax.random.normal(k4, (3, 3, 24, 128))
+    out = kd.sqdiff_units(la, lb, block_bytes=block_bytes, interpret=True)
+    np.testing.assert_allclose(out, ref.sqdiff_units(la, lb), rtol=1e-5)
+
+
+# Eq. 3 as the round calls it: one leaf, K clients, ``rows`` unit rows.
+# (leaf shape, rows, dtype, in place)
+UNIT_LEAVES = [
+    ((3, 3, 64, 128), 1, jnp.float32, True),     # VGG-9 conv2
+    ((3, 3, 3, 64), 1, jnp.float32, False),      # VGG-9 conv0
+    ((2048, 10), 1, jnp.float32, False),         # VGG-9 fc.w
+    ((64,), 1, jnp.float32, False),              # VGG-9 bias / scale
+    ((2, 16, 256), 2, jnp.bfloat16, True),       # stacked LoRA B
+    ((2, 256, 16), 2, jnp.bfloat16, False),      # stacked LoRA A
+    ((5, 129), 5, jnp.float32, False),           # ragged tail per row
+    ((3, 7, 5, 1000), 3, jnp.float32, False),    # odd sizes
+    ((2, 24, 384), 2, jnp.bfloat16, False),      # rows off the bf16 tile
+]
+
+
+@pytest.mark.parametrize("k", [1, 3, 20])
+@pytest.mark.parametrize("shape,rows,dtype,in_place", UNIT_LEAVES,
+                         ids=lambda v: str(v))
+def test_sqdiff_units_matches_ref(k, shape, rows, dtype, in_place):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(len(shape) * 31 + k))
+    a = jax.random.normal(k1, (k,) + shape, dtype=dtype)
+    b = jax.random.normal(k2, shape, dtype=dtype)
+    assert kd.leaf_view(shape, dtype, rows).in_place == in_place
+    out = kd.sqdiff_units(a, b, rows=rows, interpret=True)
+    exp = np.stack([ref.sqdiff_rowsum(a[i].reshape(rows, -1),
+                                      b.reshape(rows, -1))
+                    for i in range(k)])
+    assert out.shape == (k, rows) and out.dtype == jnp.float32
+    np.testing.assert_allclose(out, exp,
+                               rtol=1e-5 if dtype == jnp.float32 else 3e-3)
+    np.testing.assert_allclose(ref.sqdiff_units(a, b, rows), exp, rtol=1e-5)
+
+
+@pytest.mark.parametrize("in_axes", [(0, None), (0, 0), (None, 0)],
+                         ids=["locals", "both", "global"])
+@pytest.mark.parametrize("shape,rows", [((3, 3, 16, 128), 1),
+                                        ((4, 16, 256), 4), ((2048, 10), 1),
+                                        ((64,), 1)], ids=lambda v: str(v))
+def test_sqdiff_units_under_vmap(shape, rows, in_axes):
+    """The batching rule: a mapped locals axis joins the clients (one call),
+    a mapped global makes one call per entry; both equal the oracle."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(rows + len(shape)))
+    a = jax.random.normal(k1, (4, 3) + shape)
+    b = jax.random.normal(k2, (4,) + shape)
+    a_in = a if in_axes[0] == 0 else a[0]
+    b_in = b if in_axes[1] == 0 else b[0]
+    out = jax.vmap(lambda x, y: kd.sqdiff_units(x, y, rows=rows,
+                                                interpret=True),
+                   in_axes=in_axes)(a_in, b_in)
+    exp = jax.vmap(lambda x, y: ref.sqdiff_units(x, y, rows),
+                   in_axes=in_axes)(a_in, b_in)
+    assert out.shape == (4, 3, rows)
+    np.testing.assert_allclose(out, exp, rtol=1e-5)
+    calls = str(jax.make_jaxpr(jax.vmap(
+        lambda x, y: kd.sqdiff_units(x, y, rows=rows, interpret=True),
+        in_axes=in_axes))(a_in, b_in)).count("pallas_call")
+    assert calls == 1
+
+
+@pytest.mark.parametrize("shape,rows,dtype,in_place", [
+    ((3, 3, 512, 512), 1, jnp.float32, True),
+    ((4, 16, 7168), 4, jnp.bfloat16, True),
+    ((2048, 10), 1, jnp.float32, False),
+    ((4, 7168, 16), 4, jnp.bfloat16, False),
+    ((3, 3, 3, 64), 1, jnp.float32, False),
+    ((512,), 1, jnp.float32, False),
+], ids=lambda v: str(v))
+def test_leaf_view_plan(shape, rows, dtype, in_place):
+    """In place where the unit's minor dim is lane-aligned and its
+    second-minor a sublane-tile multiple; otherwise 128-lane folds that
+    pad only the tail, never rows up to a tile."""
+    v = kd.leaf_view(shape, dtype, rows)
+    assert v.in_place == in_place and v.rows == rows
+    unit = int(np.prod(shape)) // rows
+    if in_place:
+        assert (v.m, v.n) == (unit // shape[-1], shape[-1])
+        assert v.nbytes == int(np.prod(shape)) * np.dtype(dtype).itemsize
+    else:
+        assert v.n == kd.LANES and v.m == -(-unit // kd.LANES)
+
+
+def test_vgg9_in_place_share():
+    """conv2-conv7 (4,644,864 of 4,709,706 parameters) read in place."""
+    from repro.core import UnitMap
+    from repro.models import cnn
+    params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0),
+                                                    cnn.VGGConfig()))
+    umap = UnitMap.build(params)
+    plan = dict(umap.divergence_plan(params))
+    assert {p for p, v in plan.items() if v.in_place} == {
+        f"conv{i}['w']" for i in range(2, 8)}
+    share = umap.in_place_share(params)
+    assert share >= 0.98
+    assert share == pytest.approx(4644864 / sum(
+        v.nbytes // 4 for v in plan.values()))
 
 
 def _check_sqdiff_rowsum_property(r, c, seed):

@@ -222,6 +222,30 @@ def test_cross_driver_ledger_schema_equality(task, tmp_path):
         [sorted(r["comm"]) for r in segs["scan"]["rounds"]]
 
 
+@pytest.mark.parametrize("driver", ["host", "scan"])
+def test_run_meta_records_eq3_in_place_share(task, tmp_path, driver):
+    """The run header carries the share of Eq. 3's bytes the divergence
+    kernel reads in place: here l1.w (3072, 128) in place, the vectors
+    and the (128, 10) head folded."""
+    _, data = task
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    params = {"l1": {"w": jax.random.normal(ks[0], (3072, 128)) * 0.02,
+                     "b": jnp.zeros((128,))},
+              "head": {"w": jax.random.normal(ks[1], (128, 10)) * 0.1,
+                       "b": jnp.zeros((10,))}}
+    lp = str(tmp_path / "ledger.jsonl")
+    runner = run_training if driver == "host" else run_training_scan
+    kw = {"sampler": "jax"} if driver == "host" else {}
+    runner(params, _loss, data,
+           _cfg(telemetry=TelemetryConfig(ledger_path=lp)),
+           rounds=1, seed=0, **kw)
+    meta = split_runs(read_ledger(lp))[0]["meta"]
+    # bytes per client as read: 3072·128 in place; 128, 10·128 and 128
+    # (the 10 head biases padded to a lane) folded
+    want = 3072 * 128 / (3072 * 128 + 128 + 10 * 128 + 128)
+    assert meta["eq3_in_place_share"] == pytest.approx(want)
+
+
 @pytest.mark.parametrize("algo", ["fedlama", "fedavg"])
 @pytest.mark.parametrize("driver", ["host", "scan"])
 def test_ledger_resume_contiguous(task, tmp_path, algo, driver):

@@ -15,7 +15,9 @@ every test worker imports every test file.
 The last tests check where ``repro.launch.compile_cache`` puts the
 persistent compilation cache.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,11 +76,11 @@ def _rows(x):
 
 f32, i8 = jnp.float32, jnp.int8
 KERNELS = {
-    # the vmap round scores every client's leaf: vmap over K of (1, C)
+    # the vmap round scores the K stacked locals of a leaf in one call,
+    # each leaf in its own shape
     "sqdiff_rowsum": (
-        jax.vmap(lambda a, b: divergence.sqdiff_rowsum(
-            a.reshape(1, -1), b.reshape(1, -1), interpret=False)),
-        [((K,) + CONV7, f32), ((K,) + CONV7, f32)]),
+        lambda a, b: divergence.sqdiff_units(a, b, interpret=False),
+        [((K,) + CONV7, f32), (CONV7, f32)]),
     # the scan round accumulates one client's (1, C) row at a time
     "masked_accumulate": (
         lambda a, x, w: aggregate.masked_accumulate(
@@ -93,10 +95,13 @@ KERNELS = {
             _rows(l), s, w, g, _rows(v), _rows(e), interpret=False),
         [((K,) + CONV7, i8), ((K, 1), f32), ((K, 1), f32), ((K, 1), f32),
          ((K,) + CONV7, f32), ((K,) + CONV7, f32)]),
-    # a stacked bf16 leaf: 28 unit rows of 4,194,304
+    # a stacked bf16 leaf: 28 unit rows of 4,194,304, one client at a time
+    # as the scan round feeds it
     "sqdiff_rowsum_bf16": (
-        lambda a, b: divergence.sqdiff_rowsum(a, b, interpret=False),
-        [((28, 4194304), jnp.bfloat16), ((28, 4194304), jnp.bfloat16)]),
+        lambda a, b: divergence.sqdiff_units(a, b, rows=28,
+                                             interpret=False),
+        [((1, 28, 2048, 2048), jnp.bfloat16),
+         ((28, 2048, 2048), jnp.bfloat16)]),
 }
 
 
@@ -115,6 +120,120 @@ def test_fused_uplink_ef_fits_in_hbm(compile_for_chip):
     mem = compile_for_chip("fused_uplink_ef", fn, *shapes).memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BUDGET, used
+
+
+def _pads(text):
+    """(output elements, padding config) of each pad in compiled text."""
+    out = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* pad\(.*?padding=(\S+?),",
+                         text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        out.append((math.prod(dims), m.group(2)))
+    return out
+
+
+def test_sqdiff_rowsum_reads_conv7_in_place(compile_for_chip):
+    """K=20 stacked conv7 locals reach the kernel as bitcasts: no pad
+    larger than the leaf (row padding wrote f32[20,8,2359296]), and temp
+    within 5 % of the arguments (it was 426 % for all of VGG-9)."""
+    fn, shapes = KERNELS["sqdiff_rowsum"]
+    compiled = compile_for_chip("sqdiff_rowsum", fn, *shapes)
+    assert all(n <= math.prod(CONV7) for n, _ in _pads(compiled.as_text()))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 0.05 * mem.argument_size_in_bytes
+
+
+def test_vgg9_eq3_has_no_row_padding(compile_for_chip, monkeypatch):
+    """The whole of VGG-9's Eq. 3 at K=20, through the kernel as the vmap
+    round calls it: the only pads fill a unit row's tail up to a lane
+    tile, and temp stays within 5 % of the 399 MB of arguments."""
+    from repro.core import UnitMap
+    from repro.kernels import ops
+    from repro.models import cnn
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0),
+                                                    cnn.VGGConfig()))
+    umap = UnitMap.build(params)
+    leaves, tdef = jax.tree.flatten(params)
+    n = len(leaves)
+
+    def eq3(*xs):
+        return umap.divergence_batched(jax.tree.unflatten(tdef, xs[:n]),
+                                       jax.tree.unflatten(tdef, xs[n:]))
+
+    compiled = compile_for_chip(
+        "vgg9_eq3", eq3, *[((K,) + l.shape, l.dtype) for l in leaves],
+        *[(l.shape, l.dtype) for l in leaves])
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= n
+    for _, cfg in _pads(text):
+        *major, minor = cfg.split("x")
+        assert all(d == "0_0" for d in major), cfg
+        lo, hi = (int(v) for v in minor.split("_")[:2])
+        assert lo == 0 and hi < 128, cfg
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 0.05 * mem.argument_size_in_bytes
+
+
+def test_vmapped_divergence_is_one_call_per_leaf(compile_for_chip,
+                                                 monkeypatch):
+    """``jax.vmap`` over the one-client ``UnitMap.divergence`` (as
+    ``examples/quickstart.py`` calls it) compiles for the chip with the
+    kernel's HBM constraint, and the mapped axis becomes the kernel's
+    client axis: one call per leaf, no row padding, temp within 5 %."""
+    from repro.core import UnitMap
+    from repro.kernels import ops
+    from repro.models import cnn
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    params = jax.eval_shape(lambda: cnn.init_params(jax.random.PRNGKey(0),
+                                                    cnn.VGGConfig()))
+    umap = UnitMap.build(params)
+    leaves, tdef = jax.tree.flatten(params)
+    n = len(leaves)
+
+    def eq3(*xs):
+        ref = jax.tree.unflatten(tdef, xs[n:])
+        return jax.vmap(lambda p: umap.divergence(p, ref))(
+            jax.tree.unflatten(tdef, xs[:n]))
+
+    compiled = compile_for_chip(
+        "vgg9_eq3_vmap", eq3, *[((K,) + l.shape, l.dtype) for l in leaves],
+        *[(l.shape, l.dtype) for l in leaves])
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n
+    for _, cfg in _pads(text):
+        assert all(d == "0_0" for d in cfg.split("x")[:-1]), cfg
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 0.05 * mem.argument_size_in_bytes
+
+
+def test_sqdiff_operands_stay_in_hbm(compile_for_chip):
+    """Small stacked LoRA leaves made by a fusion, as local training makes
+    them: XLA would write them to VMEM (``S(1)``) and the kernel's HBM
+    reads would happen outside it; the kernel keeps both operands in HBM,
+    so its time holds the work its roofline counts."""
+    bf16 = jnp.bfloat16
+
+    def eq3(a, g, b, a2, g2, b2):
+        return (divergence.sqdiff_units((a - 0.1 * g).astype(bf16), b,
+                                        rows=4, interpret=False),
+                divergence.sqdiff_units((a2 - 0.1 * g2).astype(bf16), b2,
+                                        rows=4, interpret=False))
+
+    lora_b, lora_a = (1, 4, 16, 19200), (1, 4, 7168, 16)
+    text = compile_for_chip(
+        "sqdiff_lora", eq3, (lora_b, bf16), (lora_b, bf16),
+        (lora_b[1:], bf16), (lora_a, bf16), (lora_a, bf16),
+        (lora_a[1:], bf16)).as_text()
+    defs = dict(re.findall(r"(%[\w.\-]+) = (\S+) ", text))
+    calls = re.findall(r"= \S+ custom-call\(([^)]*)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 2
+    for ops in calls:
+        for op in ops.split(", "):
+            assert "S(1)" not in defs[op], (op, defs[op])
 
 
 # ----------------------------------------------------------------------
