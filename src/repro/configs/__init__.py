@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned configs + the paper's own VGG-9.
+"""Architecture registry: the 10 assigned configs, DeepSeek-V2-Lite, and the
+paper's own VGG-9.
 
 Every entry cites its source in the module docstring and ``source`` field.
 ``get_config(arch_id)`` returns the exact full-scale ModelConfig;
@@ -22,6 +23,7 @@ ARCHS: dict[str, str] = {
     "qwen2-7b": "qwen2_7b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "deepseek-coder-33b": "deepseek_coder_33b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(ARCHS)

@@ -1,9 +1,6 @@
 """deepseek-moe-16b [moe] — 28L d_model=2048 16H (GQA kv=16) d_ff=1408
-(per expert), vocab=102400, 2 shared + 64 routed top-6, fine-grained experts.
-[arXiv:2401.06066]
-
-Simplification (DESIGN.md §8): DeepSeekMoE keeps its first layer dense; here
-all layers are MoE with the assigned 2-shared + 64-routed top-6 structure.
+(per expert), vocab=102400, 2 shared + 64 routed top-6, fine-grained experts;
+layer 0 is dense (SwiGLU 10,944), as published. [arXiv:2401.06066]
 """
 from repro.models.config import ModelConfig
 
@@ -23,7 +20,8 @@ def config() -> ModelConfig:
         num_shared_experts=2,
         moe_top_k=6,
         moe_d_ff=1408,
-        capacity_factor=1.25,
+        first_dense_layers=1,
+        dense_d_ff=10944,
         param_dtype="bfloat16",
         compute_dtype="bfloat16",
         source="arXiv:2401.06066 (DeepSeekMoE 16B)",

@@ -25,7 +25,6 @@ def config() -> ModelConfig:
         num_shared_experts=1,
         moe_top_k=1,
         moe_d_ff=8192,
-        capacity_factor=1.25,
         param_dtype="bfloat16",
         compute_dtype="bfloat16",
         source="hf:meta-llama/Llama-4-Scout-17B-16E (family card; Maverick dims)",

@@ -441,11 +441,23 @@ def _place_state(state: dict, params, strategy, mesh) -> dict:
     return out
 
 
+def _probe_sums(probe, partition, params, frozen, batch) -> dict:
+    """The loss's own counters (``loss_fn.probe``, e.g. an MoE model's
+    tokens per expert) summed over the stacked clients of ``batch``, one
+    forward pass each at the round's global model (with one local step,
+    the routing local training saw); {} without a probe."""
+    if probe is None:
+        return {}
+    full = params if frozen is None else partition.merge(params, frozen)
+    per = jax.lax.map(lambda b: probe(full, b), batch)
+    return jax.tree.map(lambda x: x.sum(axis=0), per)
+
+
 # ======================================================================
 # Round builders
 # ======================================================================
 def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
-                              strategy):
+                              strategy, probe=None):
     """Mesh-sharded round: ``shard_map`` over ('clients'[, 'model']) axes.
 
     Every device trains its K/D local clients (vmap over the local stack),
@@ -623,15 +635,19 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
         # residual rows are device-local) ride the SAME fused psum — taps
         # must not add a second rendezvous. Disabled telemetry keeps the
         # original 3-tuple, so the compiled round is bit-identical.
-        tap_client_sq = None
+        tap_parts = {}
         if taps_on and state is not None and state.get("client"):
             with prof_mod.phase("fl.taps"):
-                tap_client_sq = taps_mod.client_sqsums(state["client"])
+                tap_parts["client_sq"] = taps_mod.client_sqsums(
+                    state["client"])
+        if taps_on and probe is not None:
+            with prof_mod.phase("fl.taps"):
+                tap_parts["probe"] = _probe_sums(probe, flcfg.partition,
+                                                 params, frozen, batch)
         with prof_mod.phase("fl.collective"):
-            if tap_client_sq is not None:
-                (parts, denom), loss_sum, comm, tap_client_sq = reduce_(
-                    ((parts, denom_loc), losses.sum(), comm_add,
-                     tap_client_sq))
+            if tap_parts:
+                (parts, denom), loss_sum, comm, tap_parts = reduce_(
+                    ((parts, denom_loc), losses.sum(), comm_add, tap_parts))
             else:
                 (parts, denom), loss_sum, comm = reduce_(
                     ((parts, denom_loc), losses.sum(), comm_add))
@@ -667,11 +683,11 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
             with prof_mod.phase("fl.taps"):
                 metrics["taps"] = taps_mod.collect(
                     strategy, state, selection, divs, umap,
-                    client_sq=(tap_client_sq if tap_client_sq is not None
-                               else {}),
-                    extra=(None if wire is None else
-                           {"wire_unit_bytes": wire["unit_bytes"],
-                            "wire_bits": wire["bits"]}))
+                    client_sq=tap_parts.get("client_sq", {}),
+                    extra={**taps_mod.probe_taps(tap_parts.get("probe", {})),
+                           **({} if wire is None else
+                              {"wire_unit_bytes": wire["unit_bytes"],
+                               "wire_bits": wire["bits"]})})
         if state is not None:
             if m > 1:
                 with prof_mod.phase("fl.collective"):
@@ -736,9 +752,11 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
                                      partition=flcfg.partition)
     strategy = make_strategy(flcfg)
     if flcfg.mesh is not None:
-        return _build_round_vmap_sharded(local_update, umap, flcfg, strategy)
+        return _build_round_vmap_sharded(local_update, umap, flcfg, strategy,
+                                         getattr(loss_fn, "probe", None))
     k = flcfg.clients_per_round
     taps_on = flcfg.telemetry is not None and flcfg.telemetry.taps
+    probe = getattr(loss_fn, "probe", None)
 
     def round_fn(params: Pytree, batch: dict, data_sizes: jnp.ndarray,
                  key: jax.Array, state: Optional[dict] = None,
@@ -829,9 +847,12 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
             with prof_mod.phase("fl.taps"):
                 metrics["taps"] = taps_mod.collect(
                     strategy, metrics.get("state"), selection, divs, umap,
-                    extra=(None if wire is None else
-                           {"wire_unit_bytes": wire["unit_bytes"],
-                            "wire_bits": wire["bits"]}))
+                    extra={**taps_mod.probe_taps(_probe_sums(
+                               probe, flcfg.partition, params, frozen,
+                               batch)),
+                           **({} if wire is None else
+                              {"wire_unit_bytes": wire["unit_bytes"],
+                               "wire_bits": wire["bits"]})})
         return new_params, metrics
 
     return round_fn
@@ -863,6 +884,7 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
                                      partition=flcfg.partition)
     k = flcfg.clients_per_round
     taps_on = flcfg.telemetry is not None and flcfg.telemetry.taps
+    probe = getattr(loss_fn, "probe", None)
 
     def round_fn(params: Pytree, batch: dict, data_sizes: jnp.ndarray,
                  key: jax.Array, state: Optional[dict] = None,
@@ -933,7 +955,9 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
         if taps_on:
             with prof_mod.phase("fl.taps"):
                 metrics["taps"] = taps_mod.collect(
-                    strategy, metrics.get("state"), selection, divs, umap)
+                    strategy, metrics.get("state"), selection, divs, umap,
+                    extra=taps_mod.probe_taps(_probe_sums(
+                        probe, flcfg.partition, params, frozen, batch)))
         return new_params, metrics
 
     return round_fn

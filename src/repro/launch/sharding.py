@@ -6,7 +6,7 @@ For every parameter/cache leaf we assign:
   ('data', or ('pod','data') multi-pod),
 - everything else replicated.
 
-Leaves under stacked top-level keys (blocks/enc_blocks) skip their leading
+Leaves under stacked top-level keys (blocks/enc_blocks/dense) skip their leading
 depth dim (it is scanned, never sharded). 1-D leaves (norm scales, biases)
 are replicated. When a dim does not divide the axis size the policy falls
 back rather than failing — this is what lets 25-head/28-head architectures
@@ -28,7 +28,7 @@ from repro.launch.mesh import data_axes
 
 Pytree = Any
 
-STACKED_TOPKEYS = ("blocks", "enc_blocks", "dec_blocks")
+STACKED_TOPKEYS = ("blocks", "enc_blocks", "dec_blocks", "dense")
 
 
 def _axis_size(mesh: Mesh, axis) -> int:

@@ -1,4 +1,5 @@
-"""Attention substrate: GQA, RoPE / M-RoPE, chunked-flash, sliding window.
+"""Attention substrate: GQA, RoPE / M-RoPE / YaRN, chunked-flash, sliding
+window.
 
 Design notes (TPU adaptation):
 - ``attend`` is a single entry point. For short KV it issues one masked
@@ -12,6 +13,7 @@ Design notes (TPU adaptation):
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -67,6 +69,63 @@ def apply_mrope(x: jnp.ndarray, positions: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+# ----------------------------------------------------------------------
+# YaRN (arXiv:2309.00071) as DeepSeek-V2 runs it: frequency blend and
+# attention scale
+# ----------------------------------------------------------------------
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original: int,
+                          beta_fast: float, beta_slow: float):
+    """(low, high): the rotary dims between which YaRN ramps from the
+    extrapolated to the interpolated frequency."""
+    def at(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(at(beta_fast))
+    high = math.ceil(at(beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_inv_freq(dim: int, theta: float, factor: float = 1.0,
+                  original: int = 0, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jnp.ndarray:
+    """(dim/2,) float32 inverse frequencies; with ``factor > 1`` the YaRN
+    blend of ``theta^(-2i/dim)`` and the same divided by ``factor``."""
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if factor <= 1.0:
+        return extra
+    low, high = yarn_correction_range(dim, theta, original, beta_fast,
+                                      beta_slow)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: extrapolate, 0: interpolate
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray,
+                     inv_freq: jnp.ndarray, mscale: float = 1.0):
+    """DeepSeek's rotary layout. x: (B, S, H, d); positions: (B, S).
+
+    The d dims are de-interleaved (pairs ``(2i, 2i+1)`` to halves) and
+    then rotated half against half; the output keeps the de-interleaved
+    order, which queries and keys share."""
+    b, s, h, d = x.shape
+    xf = x.astype(jnp.float32).reshape(b, s, h, d // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq   # (B,S,d/2)
+    cos = (jnp.cos(ang) * mscale)[:, :, None, :]
+    sin = (jnp.sin(ang) * mscale)[:, :, None, :]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
 def text_mrope_positions(batch: int, seq: int) -> jnp.ndarray:
     """Text-only M-RoPE positions: t = h = w = arange (matches HF)."""
     p = jnp.broadcast_to(jnp.arange(seq)[None, :], (batch, seq))
@@ -90,9 +149,11 @@ def _mask_bias(q_pos, kv_pos, *, causal: bool, window: int,
     return bias
 
 
-def _attend_block(q, k, v, bias):
-    """q: (B,Sq,KV,G,hd); k,v: (B,Skv,KV,hd); bias: (B?,Sq,Skv) fp32."""
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+def _attend_block(q, k, v, bias, scale=None):
+    """q: (B,Sq,KV,G,hd); k: (B,Skv,KV,hd); v: (B,Skv,KV,hd_v);
+    bias: (B?,Sq,Skv) fp32."""
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     s = jnp.einsum("bqkgd,bckd->bkgqc", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if bias.ndim == 2:
@@ -107,8 +168,9 @@ def _attend_block(q, k, v, bias):
 # Chunked-flash attention (long KV path)
 # ----------------------------------------------------------------------
 def _attend_flash(q, k, v, q_pos, kv_pos, *, causal, window, chunk,
-                  kv_valid=None, probs_bf16=False):
+                  kv_valid=None, probs_bf16=False, scale=None):
     b, sq, kvh, g, hd = q.shape
+    hd_v = v.shape[-1]
     skv = k.shape[1]
     nchunks = -(-skv // chunk)
     pad = nchunks * chunk - skv
@@ -123,11 +185,12 @@ def _attend_flash(q, k, v, q_pos, kv_pos, *, causal, window, chunk,
     kv_valid &= kv_pos[None, :] < 2**30
 
     kc = k.reshape(b, nchunks, chunk, kvh, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, nchunks, chunk, kvh, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(b, nchunks, chunk, kvh, hd_v).transpose(1, 0, 2, 3, 4)
     pc = kv_pos.reshape(nchunks, chunk)
     valc = kv_valid.reshape(b, nchunks, chunk).transpose(1, 0, 2)
 
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     qf = q.astype(jnp.float32)
 
     def body(carry, xs):
@@ -162,7 +225,7 @@ def _attend_flash(q, k, v, q_pos, kv_pos, *, causal, window, chunk,
 
     m0 = jnp.full((b, kvh, g, sq), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, kvh, g, sq), dtype=jnp.float32)
-    o0 = jnp.zeros((b, kvh, g, sq, hd), dtype=jnp.float32)
+    o0 = jnp.zeros((b, kvh, g, sq, hd_v), dtype=jnp.float32)
     (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0), (kc, vc, pc, valc))
     o = o / jnp.maximum(l[..., None], 1e-30)
     return o.transpose(0, 3, 1, 2, 4)  # (B,Sq,KV,G,hd)
@@ -176,13 +239,15 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
            causal: bool = True, window: int = 0,
            kv_valid: Optional[jnp.ndarray] = None,
            chunk: int = 1024, flash_threshold: int = 2048,
-           probs_bf16: bool = False) -> jnp.ndarray:
+           probs_bf16: bool = False,
+           scale: Optional[float] = None) -> jnp.ndarray:
     """Grouped-query attention.
 
-    q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd); H = KV·G.
-    q_pos: (Sq,) absolute positions of queries; kv_pos: (Skv,).
+    q: (B, Sq, H, hd); k: (B, Skv, KV, hd); v: (B, Skv, KV, hd_v);
+    H = KV·G. q_pos: (Sq,) absolute positions of queries; kv_pos: (Skv,).
     kv_valid: optional (B, Skv) bool (cache occupancy for decode).
-    Returns (B, Sq, H, hd) in q.dtype.
+    ``scale`` multiplies the scores (default ``hd**-0.5``).
+    Returns (B, Sq, H, hd_v) in q.dtype.
     """
     b, sq, h, hd = q.shape
     kvh = k.shape[2]
@@ -193,9 +258,9 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if skv <= flash_threshold:
         bias = _mask_bias(q_pos, kv_pos, causal=causal, window=window,
                           kv_valid=kv_valid)
-        o = _attend_block(qg, k, v, bias)
+        o = _attend_block(qg, k, v, bias, scale)
     else:
         o = _attend_flash(qg, k, v, q_pos, kv_pos, causal=causal,
                           window=window, chunk=chunk, kv_valid=kv_valid,
-                          probs_bf16=probs_bf16)
-    return o.reshape(b, sq, h, hd).astype(q.dtype)
+                          probs_bf16=probs_bf16, scale=scale)
+    return o.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)

@@ -31,12 +31,32 @@ class ModelConfig:
     mrope_sections: tuple[int, ...] = (16, 24, 24)
     sliding_window: int = 0                  # 0 = full attention
 
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1);
+    # kv_lora_rank > 0 switches every attention layer to MLA, with
+    # num_heads heads (num_kv_heads and head_dim are then unused)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rotary scaling (rope_factor 1 = plain RoPE)
+    rope_factor: float = 1.0
+    rope_original_max_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # MoE
     num_experts: int = 0
     num_shared_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True              # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0              # leading dense layers (MoE)
+    dense_d_ff: int = 0                      # their SwiGLU width
+    aux_loss_coef: float = 0.01              # load-balance term in lm_loss
     expert_units: bool = False               # beyond-paper: expert-level FedLDF units
 
     # SSM (mamba2 SSD)
@@ -68,6 +88,21 @@ class ModelConfig:
         return self.d_model // max(1, self.num_heads)  # 0 heads: attn-free
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Query/key width per head: MLA's nope + rope parts, else hd."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.hd
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_layers - self.first_dense_layers
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -87,10 +122,18 @@ class ModelConfig:
         """Approximate parameter count N (for 6·N·D model-FLOPs)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         per_layer = 0
-        if self.family in ("dense", "moe", "hybrid", "vlm", "audio"):
+        attn = 0
+        if self.is_mla:
+            h, r = self.num_heads, self.kv_lora_rank
+            attn = (d * h * self.qk_head_dim
+                    + d * (r + self.qk_rope_head_dim)
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        elif self.family in ("dense", "moe", "hybrid", "vlm", "audio"):
             qdim = self.num_heads * self.hd
             kvdim = self.num_kv_heads * self.hd
-            per_layer += d * qdim + 2 * d * kvdim + qdim * d      # q,k,v,o
+            attn = d * qdim + 2 * d * kvdim + qdim * d            # q,k,v,o
+        per_layer += attn
         if self.family == "hybrid" or self.family == "ssm":
             di, n, h = self.ssm_d_inner, self.ssm_state, self.ssm_heads
             per_layer += d * (2 * di + 2 * n + h) + di * d        # in/out proj
@@ -101,6 +144,10 @@ class ModelConfig:
         elif f > 0:
             per_layer += 3 * d * f                                # SwiGLU
         total = self.num_layers * per_layer
+        if self.num_experts > 0 and self.first_dense_layers:
+            # the leading dense layers carry a SwiGLU, not the experts
+            total += self.first_dense_layers * (
+                3 * d * (self.dense_d_ff or f) - (per_layer - attn))
         if self.is_encdec:
             enc_layer = (d * self.num_heads * self.hd * 2
                          + 2 * d * self.num_kv_heads * self.hd + 3 * d * f)
@@ -115,9 +162,9 @@ class ModelConfig:
         if self.num_experts == 0:
             return self.param_count()
         d = self.d_model
-        dense_like = self.param_count() - self.num_layers * (
+        dense_like = self.param_count() - self.moe_layers * (
             self.num_experts * 3 * d * self.moe_d_ff)
-        active_moe = self.num_layers * self.moe_top_k * 3 * d * self.moe_d_ff
+        active_moe = self.moe_layers * self.moe_top_k * 3 * d * self.moe_d_ff
         return int(dense_like + active_moe)
 
     # ------------------------------------------------------------------
@@ -148,6 +195,12 @@ class ModelConfig:
             num_shared_experts=min(self.num_shared_experts, 1),
             moe_top_k=min(self.moe_top_k, 2),
             moe_d_ff=64 if self.num_experts else 0,
+            first_dense_layers=min(self.first_dense_layers, 1),
+            dense_d_ff=256 if self.dense_d_ff else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=16 if self.is_mla else 0,
+            qk_rope_head_dim=8 if self.is_mla else 0,
+            v_head_dim=16 if self.is_mla else 0,
             ssm_state=min(self.ssm_state, 16),
             ssm_head_dim=32,
             ssm_chunk=16,

@@ -6,6 +6,10 @@
 - ``decode_step`` — ONE new token against the cache (the program lowered for
   the ``decode_32k`` / ``long_500k`` input shapes).
 
+MLA models cache each head's expanded key (nope + rope) and value; the
+leading dense layers of an MoE model (``params["dense"]``) hold the first
+rows of every cache leaf and run before the MoE blocks.
+
 Ring buffer: the KV buffer has ``W`` slots; token at absolute position ``p``
 writes slot ``p mod W``. With ``W = sliding_window`` this *is* sliding-window
 attention (what makes dense architectures eligible for ``long_500k``); with
@@ -25,7 +29,8 @@ from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig, dtype_of
 from repro.models.layers import mlp_fwd, rms_norm
 from repro.models.transformer import (_embed_tokens, _enc_kv_all, _encode,
-                                      _qkv, block_kind)
+                                      _mla_qkv, _qkv, block_kind,
+                                      mla_softmax_scale)
 
 Pytree = Any
 
@@ -39,12 +44,15 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
     """Empty cache for ``seq_len`` context. Leaves stacked over layers."""
     dt = dtype_of(cfg.compute_dtype)
     l, hd, kvh = cfg.num_layers, cfg.hd, cfg.num_kv_heads
+    hd_v = hd
+    if cfg.is_mla:
+        kvh, hd, hd_v = cfg.num_heads, cfg.qk_head_dim, cfg.v_head_dim
     w = cache_window(cfg, seq_len)
     kind = block_kind(cfg)
     cache: Pytree = {"pos": jnp.zeros((), jnp.int32)}
     if kind in ("dense", "moe", "hybrid", "dec"):
         cache["k"] = jnp.zeros((l, batch, w, kvh, hd), dt)
-        cache["v"] = jnp.zeros((l, batch, w, kvh, hd), dt)
+        cache["v"] = jnp.zeros((l, batch, w, kvh, hd_v), dt)
     if kind in ("ssm", "hybrid"):
         sc = ssm_mod.init_ssm_cache(cfg, batch, dt)
         cache["ssm_conv"] = jnp.broadcast_to(
@@ -55,6 +63,25 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
         cache["cross_k"] = jnp.zeros((l, batch, enc_len, kvh, hd), dt)
         cache["cross_v"] = jnp.zeros((l, batch, enc_len, kvh, hd), dt)
     return cache
+
+
+def _attn_qkv(p, cfg: ModelConfig, h, positions):
+    return (_mla_qkv if cfg.is_mla else _qkv)(p, cfg, h, positions)
+
+
+def _scan_layers(params, cfg: ModelConfig, body, x, xs):
+    """``body(kind)`` scanned over ``(layer params, xs)``: the leading
+    dense layers, if any, and then the blocks. ``xs`` leaves hold every
+    layer, dense ones first; so do the returned ys."""
+    kind = block_kind(cfg)
+    if "dense" not in params:
+        return jax.lax.scan(body(kind), x, (params["blocks"], xs))
+    n = cfg.first_dense_layers
+    x, ys_d = jax.lax.scan(body("dense"), x, (
+        params["dense"], jax.tree.map(lambda a: a[:n], xs)))
+    x, ys = jax.lax.scan(body(kind), x, (
+        params["blocks"], jax.tree.map(lambda a: a[n:], xs)))
+    return x, jax.tree.map(lambda a, b: jnp.concatenate([a, b]), ys_d, ys)
 
 
 # ----------------------------------------------------------------------
@@ -71,8 +98,8 @@ def prefill(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
     buffer (oldest entry evicted).
     """
     b, s = tokens.shape
-    kind = block_kind(cfg)
     w = cache_window(cfg, max_len or s)
+    scale = mla_softmax_scale(cfg) if cfg.is_mla else None
     x = _embed_tokens(params, cfg, tokens, embeddings)
     if cfg.mrope:
         positions = attn.text_mrope_positions(b, s)
@@ -86,15 +113,17 @@ def prefill(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
         enc_out = _encode(params, cfg, enc_inputs)
         enc_kv = _enc_kv_all(params, cfg, enc_out)
 
-    def body(x, xs):
-        blk = xs[0] if cfg.is_encdec else xs
-        ekv = (xs[1], xs[2]) if cfg.is_encdec else None
+    def body(kind):
+        return lambda x, xs: prefill_layer(x, xs, kind)
+
+    def prefill_layer(x, xs, kind):
+        blk, ekv = xs
         ys = {}
         h = rms_norm(x, blk["ln1"])
         if kind in ("dense", "moe", "hybrid", "dec"):
-            q, k, v = _qkv(blk["attn"], cfg, h, positions)
+            q, k, v = _attn_qkv(blk["attn"], cfg, h, positions)
             o = attn.attend(q, k, v, q_pos=pos1d, kv_pos=pos1d, causal=True,
-                            window=cfg.sliding_window)
+                            window=cfg.sliding_window, scale=scale)
             o = jnp.einsum("bsf,fd->bsd", o.reshape(b, s, -1),
                            blk["attn"]["wo"])
             # keep the last min(s, w) (post-RoPE) keys/values, ring-aligned
@@ -129,15 +158,14 @@ def prefill(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
             ys["cross_k"], ys["cross_v"] = ekv
         h2 = rms_norm(x, blk["ln2"])
         if kind == "moe":
-            out, _ = moe_mod.moe_fwd(blk["moe"], h2, cfg)
+            out, _, _ = moe_mod.moe_fwd(blk["moe"], h2, cfg)
             x = x + out
         else:
             x = x + mlp_fwd(blk["mlp"], h2)
         return x, ys
 
-    xs = (params["blocks"],) + tuple(enc_kv) if cfg.is_encdec \
-        else params["blocks"]
-    x, ys = jax.lax.scan(body, x, xs)
+    x, ys = _scan_layers(params, cfg, body, x,
+                         tuple(enc_kv) if cfg.is_encdec else None)
 
     x = rms_norm(x, params["final"]["norm"])
     head = (params["embed"]["tok"].T if cfg.tie_embeddings
@@ -160,8 +188,8 @@ def decode_step(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
                 cache: Pytree):
     """One token. tokens: (B, 1) int32. Returns (logits (B, V), cache')."""
     b = tokens.shape[0]
-    kind = block_kind(cfg)
     pos = cache["pos"]
+    scale = mla_softmax_scale(cfg) if cfg.is_mla else None
     x = _embed_tokens(params, cfg, tokens)
     if cfg.mrope:
         positions = jnp.broadcast_to(pos, (3, b, 1))
@@ -175,12 +203,15 @@ def decode_step(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
         n_valid = jnp.minimum(pos + 1, w)
         kv_valid = jnp.broadcast_to(jnp.arange(w)[None, :] < n_valid, (b, w))
 
-    def body(x, xs):
-        blk = xs["blk"]
+    def body(kind):
+        return lambda x, xs: decode_layer(x, xs, kind)
+
+    def decode_layer(x, xs, kind):
+        blk, xs = xs
         ys = {}
         h = rms_norm(x, blk["ln1"])
         if kind in ("dense", "moe", "hybrid", "dec"):
-            q, k, v = _qkv(blk["attn"], cfg, h, positions)
+            q, k, v = _attn_qkv(blk["attn"], cfg, h, positions)
             ck = jax.lax.dynamic_update_slice_in_dim(
                 xs["k"], k.astype(xs["k"].dtype), slot, axis=1)
             cv = jax.lax.dynamic_update_slice_in_dim(
@@ -189,7 +220,8 @@ def decode_step(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
             o = attn.attend(q, ck, cv,
                             q_pos=jnp.full((1,), pos, jnp.int32),
                             kv_pos=jnp.zeros((w,), jnp.int32),
-                            causal=False, window=0, kv_valid=kv_valid)
+                            causal=False, window=0, kv_valid=kv_valid,
+                            scale=scale)
             o = jnp.einsum("bsf,fd->bsd", o.reshape(b, 1, -1),
                            blk["attn"]["wo"])
             if kind == "hybrid":
@@ -214,17 +246,15 @@ def decode_step(params: Pytree, cfg: ModelConfig, tokens: jnp.ndarray,
             ys["cross_k"], ys["cross_v"] = xs["cross_k"], xs["cross_v"]
         h2 = rms_norm(x, blk["ln2"])
         if kind == "moe":
-            out, _ = moe_mod.moe_fwd(blk["moe"], h2, cfg)
+            out, _, _ = moe_mod.moe_fwd(blk["moe"], h2, cfg)
             x = x + out
         else:
             x = x + mlp_fwd(blk["mlp"], h2)
         return x, ys
 
-    xs = {"blk": params["blocks"]}
-    for key in ("k", "v", "ssm_conv", "ssm_state", "cross_k", "cross_v"):
-        if key in cache:
-            xs[key] = cache[key]
-    x, ys = jax.lax.scan(body, x, xs)
+    xs = {key: cache[key] for key in ("k", "v", "ssm_conv", "ssm_state",
+                                      "cross_k", "cross_v") if key in cache}
+    x, ys = _scan_layers(params, cfg, body, x, xs)
 
     x = rms_norm(x, params["final"]["norm"])
     head = (params["embed"]["tok"].T if cfg.tie_embeddings

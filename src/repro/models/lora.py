@@ -2,7 +2,10 @@
 
 ``inject_lora`` drops low-rank factor pairs ``{"a": (L, d_in, r),
 "b": (L, r, d_out)}`` next to the stacked dense projections they adapt
-(``blocks["attn"]["lora"]["wq"]``, ...). ``b`` is zero-initialised, so the
+(``blocks["attn"]["lora"]["wq"]``, ...). An expert tensor
+``(L, E, d_in, d_out)`` gets one pair per expert, ``a: (L, E, d_in, r)``
+and ``b: (L, E, r, d_out)``, which the MoE layer applies per group of
+rows (``models/moe.py``). ``b`` is zero-initialised, so the
 adapted forward equals the base forward bit-for-bit at injection time —
 training moves only the factors. The forward hookup lives in
 :func:`repro.models.layers.lora_dense`.
@@ -16,13 +19,14 @@ units — the stacked (L, ...) leading axis folds into the existing
 
 Adapted projections per block module (only those present are touched):
 
-    attn: wq wk wv wo          (dense / moe / hybrid / enc / dec families)
-    mlp:  w_gate w_up w_down   (all non-moe FFN blocks)
-    ssm:  in_proj out_proj     (mamba2 / hybrid families)
+    attn:       wq wk wv wo        (dense / moe / hybrid / enc / dec families)
+                wq wkv_a wkv_b wo  (multi-head latent attention)
+    mlp:        w_gate w_up w_down (all non-moe FFN blocks)
+    moe:        w_gate w_up w_down (every routed expert)
+    moe/shared: w_gate w_up w_down (the shared experts' SwiGLU)
+    ssm:        in_proj out_proj   (mamba2 / hybrid families)
 
-Cross-attention and MoE expert tensors are intentionally not adapted —
-the classic LoRA recipe targets self-attention + FFN, and expert tensors
-carry an extra (E,) axis the factor layout does not model.
+Cross-attention and the router are not adapted.
 """
 from __future__ import annotations
 
@@ -36,16 +40,38 @@ from repro.core.partition import ParamPartition
 
 Pytree = Any
 
-# module-name -> projection names eligible for adapters (ndim-3 stacked
-# (L, d_in, d_out) leaves only; missing modules/names are skipped).
+# module path -> projection names eligible for adapters (stacked
+# (L, [E,] d_in, d_out) leaves only; missing modules/names are skipped).
 LORA_TARGETS: Mapping[str, Tuple[str, ...]] = {
-    "attn": ("wq", "wk", "wv", "wo"),
+    "attn": ("wq", "wk", "wv", "wo", "wkv_a", "wkv_b"),
     "mlp": ("w_gate", "w_up", "w_down"),
+    "moe": ("w_gate", "w_up", "w_down"),
+    "moe/shared": ("w_gate", "w_up", "w_down"),
     "ssm": ("in_proj", "out_proj"),
 }
 
 # stacked-block subtrees adapters may live under (see transformer.init_params)
-LORA_SUBTREES: Tuple[str, ...] = ("blocks", "enc_blocks")
+LORA_SUBTREES: Tuple[str, ...] = ("blocks", "enc_blocks", "dense")
+
+
+def _adapt_module(key, mdict: dict, projs, rank: int):
+    """(key, module dict with its ``lora`` entry, adapters injected)."""
+    lora = dict(mdict.get("lora", {}))
+    injected = 0
+    for name in projs:
+        w = mdict.get(name)
+        if w is None or getattr(w, "ndim", 0) not in (3, 4):
+            continue
+        *lead, din, dout = w.shape
+        r = min(rank, din, dout)
+        key, ka = jax.random.split(key)
+        a = (jax.random.normal(ka, (*lead, din, r))
+             / np.sqrt(din)).astype(w.dtype)
+        lora[name] = {"a": a, "b": jnp.zeros((*lead, r, dout), w.dtype)}
+        injected += 1
+    if lora:
+        mdict = dict(mdict, lora=lora)
+    return key, mdict, injected
 
 
 def inject_lora(key, params: Pytree, rank: int,
@@ -67,26 +93,22 @@ def inject_lora(key, params: Pytree, rank: int,
         if sub not in params:
             continue
         blocks = dict(params[sub])
-        for mod, projs in targets.items():
-            if mod not in blocks:
-                continue
-            mdict = dict(blocks[mod])
-            lora = dict(mdict.get("lora", {}))
-            for name in projs:
-                w = mdict.get(name)
-                if w is None or getattr(w, "ndim", 0) != 3:
+        # outer modules first, so a nested module ("moe/shared") lands in
+        # its parent's updated dict
+        for path in sorted(targets, key=lambda p: p.count("/")):
+            *parents, mod = path.split("/")
+            node = blocks
+            for seg in parents:         # copy the parents, never mutate
+                if not isinstance(node.get(seg), dict):
+                    break
+                node[seg] = dict(node[seg])
+                node = node[seg]
+            else:
+                if mod not in node:
                     continue
-                depth, din, dout = w.shape
-                r = min(rank, din, dout)
-                key, ka = jax.random.split(key)
-                a = (jax.random.normal(ka, (depth, din, r))
-                     / np.sqrt(din)).astype(w.dtype)
-                lora[name] = {"a": a, "b": jnp.zeros((depth, r, dout),
-                                                     w.dtype)}
-                injected += 1
-            if lora:
-                mdict["lora"] = lora
-                blocks[mod] = mdict
+                key, node[mod], n = _adapt_module(key, dict(node[mod]),
+                                                  targets[path], rank)
+                injected += n
         out[sub] = blocks
     if injected == 0:
         raise ValueError(

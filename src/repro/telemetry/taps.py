@@ -13,6 +13,11 @@ the engines can see:
   ``psum`` (no extra rendezvous, no host sync); the unsharded engines sum
   the same quantity over all K rows directly, so the tapped value is
   driver-independent.
+- :func:`probe_taps` — the loss's own counters (``loss_fn.probe``),
+  summed over the round's clients by the engine; an MoE model's
+  ``expert_tokens`` (tokens routed to each expert, per MoE layer) gains
+  ``expert_load_ratio``, the busiest expert's tokens over the mean, per
+  layer.
 - :func:`collect` — assemble the final per-round tap dict: the strategy
   hook on replicated inputs (selection/divergence/global state) plus
   ``state_<name>_norm`` entries from the client-row partials.
@@ -42,6 +47,16 @@ def client_sqsums(client: dict) -> dict:
         parts = [jnp.sum(jnp.square(l.astype(jnp.float32)))
                  for l in jax.tree.leaves(rows)]
         out[name] = sum(parts, jnp.float32(0.0))
+    return out
+
+
+def probe_taps(sums: dict) -> dict:
+    """The round's summed probe counters, plus derived load ratios."""
+    out = dict(sums)
+    if "expert_tokens" in sums:
+        t = sums["expert_tokens"]
+        out["expert_load_ratio"] = t.max(axis=-1) / jnp.maximum(
+            t.mean(axis=-1), 1e-9)
     return out
 
 

@@ -1,6 +1,7 @@
 """Serving-path correctness: prefill + incremental decode must reproduce the
 full-forward logits for every model family (incl. sliding window, SSM state,
-MoE routing, M-RoPE, enc-dec cross attention)."""
+MoE routing, latent attention with YaRN and a leading dense layer, M-RoPE,
+enc-dec cross attention)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +25,13 @@ CASES = [
     mk("dense", sliding_window=8),
     mk("dense", qk_norm=True, qkv_bias=True),
     mk("moe", num_experts=4, moe_top_k=2, moe_d_ff=32, num_shared_experts=1,
-       d_ff=0, capacity_factor=8.0),
+       d_ff=0),
+    mk("moe", name="t-mla-moe", num_experts=4, moe_top_k=2, moe_d_ff=32,
+       num_shared_experts=1, d_ff=0, kv_lora_rank=16, qk_nope_head_dim=8,
+       qk_rope_head_dim=8, v_head_dim=12, rope_factor=4.0,
+       rope_original_max_positions=8, yarn_mscale=0.707,
+       yarn_mscale_all_dim=0.707, norm_topk_prob=False,
+       first_dense_layers=1, dense_d_ff=48),
     mk("ssm", ssm_state=8, ssm_head_dim=16, ssm_chunk=8),
     mk("hybrid", ssm_state=8, ssm_head_dim=16, ssm_chunk=8),
     mk("vlm", mrope=True, mrope_sections=(4, 2, 2)),

@@ -6,7 +6,8 @@ the chip would refuse: tiles not aligned to its layout, more VMEM than a
 kernel may use, a program that does not fit in HBM. Each kernel is fed
 as the round engine feeds it: VGG-9's largest leaf, conv7's
 (3, 3, 512, 512) weight, reshaped to one unit row of 2,359,296, stacked
-over K=20 clients where the engine stacks them.
+over K=20 clients where the engine stacks them. The MoE layer's grouped
+matmul (megablox) is compiled at DeepSeek-V2-Lite's widths.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library, and
@@ -25,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import aggregate, divergence, uplink
+from repro.models import moe
 
 K = 20
 CONV7 = (3, 3, 512, 512)
@@ -234,6 +236,39 @@ def test_sqdiff_operands_stay_in_hbm(compile_for_chip):
     for ops in calls:
         for op in ops.split(", "):
             assert "S(1)" not in defs[op], (op, defs[op])
+
+
+def _lora_expert_grads(x, w, a, b, gs, c):
+    """Gradients w.r.t. the rows and the LoRA factors of one expert
+    projection as the MoE layer makes it (the base ``w`` is frozen)."""
+    gm = lambda u, v: moe.grouped_matmul(u, v, gs, kernel="megablox")
+    loss = lambda x, a, b: jnp.sum(
+        (c * (gm(x, w) + gm(gm(x, a), b))).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(x, a, b)
+
+
+@pytest.mark.parametrize("clients", [0, 2])
+def test_grouped_matmul_compiles_for_v5e(compile_for_chip, clients):
+    """The MoE layer's megablox path at DeepSeek-V2-Lite's widths (4,096
+    tokens x top-6 rows, 64 experts, 2048 -> 1408, rank 16), alone and
+    vmapped over stacked clients (the batching rule's ``lax.map``). Six
+    kernels either way: the rank-16 forward product the gradients need,
+    three row gradients (``gmm``) and the two factors' (``tgmm``); the
+    frozen base takes no weight gradient and no kernel reads it twice."""
+    bf16, e, d, f, r = jnp.bfloat16, 64, 2048, 1408, 16
+    rows = 4096 * 6
+    lead = (clients,) if clients else ()
+    fn = (jax.vmap(_lora_expert_grads, in_axes=(0, None, None, None, 0, 0))
+          if clients else _lora_expert_grads)
+    text = compile_for_chip(
+        f"grouped_matmul_{clients}", fn, (lead + (rows, d), bf16),
+        ((e, d, f), bf16), ((e, d, r), bf16), ((e, r, f), bf16),
+        (lead + (e,), jnp.int32), (lead + (rows, f), bf16)).as_text()
+    kernels = re.findall(r"%(\S+) = \S+ custom-call\([^)]*\)[^\n]*"
+                         r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(kernels) == 6, kernels
+    assert sum("tgmm" in k for k in kernels) == 2, kernels
+    assert "ragged" not in text
 
 
 # ----------------------------------------------------------------------
