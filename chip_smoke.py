@@ -18,8 +18,9 @@ and the device-memory peak:
    ``kernels/ref.py``, each compiled program holding a ``tpu_custom_call``.
 3. engine: ``run_training_scan`` for 3 rounds with an eval after each,
    cold and then warm, against ``run_training(sampler="jax")``.
-4. sequential clients: ``mode="scan"`` (the streaming Eq. 5 accumulate)
-   for 2 rounds, against the stacked-client engine's first 2 rounds.
+4. sequential clients: ``mode="scan"`` (FedLDF's one client pass, each
+   unit's top-n locals kept as the clients train) for 2 rounds, against
+   the stacked-client engine's first 2 rounds.
 5. packed uplink with error feedback: ``run_training`` with
    ``CompressionConfig(bits=8, error_feedback=True)`` for 3 rounds.
 
@@ -331,7 +332,7 @@ def phase_engine(smoke: Smoke) -> str:
 
 
 # ======================================================================
-# Phase 4: sequential clients (streaming masked_accumulate)
+# Phase 4: sequential clients (one pass, each unit's top-n locals kept)
 # ======================================================================
 def phase_sequential(smoke: Smoke) -> str:
     from repro.federated import run_training_scan

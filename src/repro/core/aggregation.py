@@ -6,8 +6,13 @@ Two execution layouts are supported:
   with a leading client axis; aggregation is a masked weighted mean over that
   axis (small models — the paper's own VGG-9 regime).
 - **streaming** (``scan`` client mode): clients are visited sequentially and
-  added into a float32 accumulator with per-unit weights (large models; see
-  DESIGN.md §3 two-phase recompute).
+  added into a float32 accumulator with per-unit weights (large models; a
+  strategy that selects from every client's score trains each client
+  again for it).
+- **top-n slots** (``scan`` client mode, FedLDF): as clients are visited,
+  each unit keeps its n most divergent locals so far (Eq. 4's top-n), and
+  Eq. 5 reduces the slots once selection is known, so no client trains
+  twice.
 
 The stacked layout additionally supports a *client-sharded* reduction
 (``aggregate_stacked(..., axis_name='clients')`` inside ``shard_map``): each
@@ -27,7 +32,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.units import UnitMap, tree_zeros_like
+from repro.core.units import UnitMap, tree_cast, tree_zeros_like
 
 Pytree = Any
 
@@ -266,3 +271,60 @@ def streaming_finalize(acc: Pytree, umap: UnitMap, denom: jnp.ndarray,
     fb = umap.scale_by_unit(fallback, 1.0 - alive)
     return jax.tree.map(lambda a, b, g: (a + b.astype(jnp.float32)).astype(g.dtype),
                         kept, fb, fallback)
+
+
+# ----------------------------------------------------------------------
+# Top-n slots (scan over clients) — Eq. 4's per-unit top-n kept as the
+# clients train, so Eq. 5 needs no second training pass.
+# ----------------------------------------------------------------------
+def topn_insert(slots: list, score: jnp.ndarray, who: jnp.ndarray,
+                local: Pytree, div: jnp.ndarray, client: jnp.ndarray,
+                umap: UnitMap) -> tuple[list, jnp.ndarray, jnp.ndarray]:
+    """Insert one client's ``local`` into each unit's n best so far.
+
+    ``slots`` is a list of n parameter trees at the parameter dtype,
+    ``score`` (n, U) their Eq. 3 divergences, descending per unit (−inf
+    where a slot is empty), ``who`` (n, U) the client each slot holds.
+    Per unit, the slots from the first whose score is strictly lower than
+    ``div`` shift down one and the local takes that slot. The comparison
+    is strict, so a tie keeps the earlier client, as ``lax.top_k`` does:
+    after every client has been inserted in index order, ``who`` holds
+    exactly the clients ``selection.topn_divergence`` picks. Stacked
+    leaves take the select row by row, one row per unit.
+    """
+    n = score.shape[0]
+    pos = jnp.sum(score >= div[None, :], axis=0)          # (U,)
+    j = jnp.arange(n)[:, None]
+    keep, put = j < pos, j == pos                           # (n, U)
+
+    def shift(a, new):
+        prev = jnp.concatenate([a[:1], a[:-1]])             # slot j - 1
+        return jnp.where(keep, a, jnp.where(put, new[None], prev))
+
+    def pick(mask, a, b):
+        m = umap.expand_to_leaves(a, mask.astype(jnp.float32))
+        return jax.tree.map(lambda m_, x, y: jnp.where(m_ != 0, x, y),
+                            m, a, b)
+
+    new_slots = [pick(keep[i], slots[i],
+                      local if i == 0 else pick(put[i], local, slots[i - 1]))
+                 for i in range(n)]
+    return (new_slots, shift(score, div),
+            shift(who, jnp.full(div.shape, client, who.dtype)))
+
+
+def topn_aggregate(slots: list, who: jnp.ndarray, umap: UnitMap,
+                   selection: jnp.ndarray, data_sizes: jnp.ndarray,
+                   fallback: Pytree) -> Pytree:
+    """Eq. 5 over the kept slots: ``Σ_j w[who_j,u]·slot_j / denom[u]``
+    with ``w, denom = unit_weights(selection, data_sizes)``, summed in
+    float32 and finished as :func:`streaming_finalize` does (dead units
+    from ``fallback``, cast back to the parameter dtype). ``who`` must
+    hold the selected clients (:func:`topn_insert` over every client)."""
+    w, denom = unit_weights(selection, data_sizes)
+    frac = (jnp.take_along_axis(w, who, axis=0)
+            / jnp.where(denom > 0, denom, 1.0)[None, :])      # (n, U)
+    parts = [umap.scale_by_unit(tree_cast(s, jnp.float32), f)
+             for s, f in zip(slots, frac)]
+    acc = jax.tree.map(lambda *xs: sum(xs[1:], xs[0]), *parts)
+    return streaming_finalize(acc, umap, denom, fallback)

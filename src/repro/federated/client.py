@@ -3,8 +3,9 @@
 A client receives the global model, runs ``local_steps`` optimizer steps on
 its local batch (the paper uses exactly one SGD step — "after one time local
 training"), and returns its local model. The update is a *pure deterministic*
-function of (global params, client batch) — the property that enables the
-two-phase recompute execution mode for large models (DESIGN.md §3).
+function of (global params, client batch) — the property that lets the
+scan engine train a client again for Eq. 5 instead of holding its local
+(``server.scan_client_passes``).
 """
 from __future__ import annotations
 

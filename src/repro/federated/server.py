@@ -76,7 +76,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import aggregation as agg
 from repro.core import comm as comm_mod
 from repro.core.partition import ParamPartition, partition_counts
-from repro.core.units import UnitMap
+from repro.core.units import UnitMap, tree_zeros_like
 from repro.core.wire import CompressionConfig
 from repro.data.device import ClientShards
 from repro.federated.client import make_local_update
@@ -858,18 +858,40 @@ def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
     return round_fn
 
 
+def scan_client_passes(strategy) -> int:
+    """How many times the scan round trains each client: twice where the
+    strategy needs every client's Eq. 3 scores before it selects and its
+    selection is not the per-unit top-n the round can keep as the clients
+    train (Eq. 5 then recomputes each local), else once."""
+    if strategy.needs_divergence and not (
+            strategy.selects_topn_divergence and strategy.eq5_weighted):
+        return 2
+    return 1
+
+
 def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
                      opt: Optimizer | None = None):
-    """Round function with sequential clients + two-phase recompute.
+    """Round function with sequential clients.
 
-    Memory (``eq5_weighted`` strategies): O(global + 1 local +
-    1 accumulator) models, independent of K — selected layers are streamed
-    into the Eq. 5 accumulator as each client trains. Strategies whose
-    aggregation is not an Eq. 5 weighted mean (e.g. FedADP's element-wise
-    neuron masks) instead have their sequentially-trained locals *stacked*
-    by the scan and fed to the same :meth:`FLStrategy.aggregate` hook used
-    in vmap mode — O(K) parameter memory, but still O(1) activation
-    memory, which is the scan engine's binding constraint for deep models.
+    Each client trains once (:func:`scan_client_passes`), except where the
+    strategy needs every client's Eq. 3 scores before it selects and its
+    selection is not the per-unit top-n: then phase 1 trains each client
+    for Eq. 3 and phase 2 trains it again for Eq. 5 (FedLAMA).
+
+    Memory, ``eq5_weighted`` strategies: O(global + 1 local + 1 float32
+    accumulator) models, independent of K — selected layers are streamed
+    into the Eq. 5 accumulator as each client trains. A top-n strategy
+    (``selects_topn_divergence``, FedLDF) holds n locals at the parameter
+    dtype in place of the accumulator: each unit keeps its n most
+    divergent locals as the clients train
+    (:func:`repro.core.aggregation.topn_insert`), and Eq. 5 reduces them
+    after selection — at top-2 over bf16 adapters the same bytes as the
+    float32 accumulator. Strategies whose aggregation is not an Eq. 5
+    weighted mean (e.g. FedADP's element-wise neuron masks) instead have
+    their sequentially-trained locals *stacked* by the scan and fed to the
+    same :meth:`FLStrategy.aggregate` hook used in vmap mode — O(K)
+    parameter memory, but still O(1) activation memory, which is the scan
+    engine's binding constraint for deep models.
     """
     if getattr(flcfg, "compression", None) is not None or \
             getattr(flcfg, "quantize_bits", 0):
@@ -885,6 +907,9 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
     k = flcfg.clients_per_round
     taps_on = flcfg.telemetry is not None and flcfg.telemetry.taps
     probe = getattr(loss_fn, "probe", None)
+    # Eq. 4 is the per-unit top-n of the Eq. 3 scores: keep those locals
+    # as the clients train instead of training every client again
+    topn = strategy.needs_divergence and scan_client_passes(strategy) == 1
 
     def round_fn(params: Pytree, batch: dict, data_sizes: jnp.ndarray,
                  key: jax.Array, state: Optional[dict] = None,
@@ -894,8 +919,29 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
         # each client-loop scan is scoped fl.local as a whole (its slicing
         # and stacking included); the Eq. 3 / Eq. 5 work inside the body
         # carries its own, inner phase
+        if topn:
+            # ---- one pass: Eq. 3, and each unit's top-n locals kept
+            def one_pass(carry, inp):
+                batch_k, client = inp
+                slots, score, who = carry
+                local, loss = lu(params, batch_k)
+                with prof_mod.phase("fl.eq3"):
+                    div = umap.divergence(local, params)
+                with prof_mod.phase("fl.eq5"):
+                    carry = agg.topn_insert(slots, score, who, local, div,
+                                            client, umap)
+                return carry, (div, loss)
+
+            with prof_mod.phase("fl.eq5"):
+                shape = (flcfg.top_n, umap.num_units)
+                slots0 = ([tree_zeros_like(params)] * flcfg.top_n,
+                          jnp.full(shape, -jnp.inf, jnp.float32),
+                          jnp.zeros(shape, jnp.int32))
+            with prof_mod.phase("fl.local"):
+                (slots, _, who), (divs, losses1) = jax.lax.scan(
+                    one_pass, slots0, (batch, jnp.arange(k)))
         # ---- phase 1: divergence feedback (only if the policy needs it)
-        if strategy.needs_divergence:
+        elif strategy.needs_divergence:
             def phase1(carry, batch_k):
                 local, loss = lu(params, batch_k)
                 with prof_mod.phase("fl.eq3"):
@@ -911,7 +957,11 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
             selection = strategy.select_with_state(
                 state, divs, key, k, umap.num_units, flcfg.top_n)
 
-        if strategy.eq5_weighted:
+        if topn:
+            with prof_mod.phase("fl.eq5"):
+                new_params = agg.topn_aggregate(slots, who, umap, selection,
+                                                data_sizes, params)
+        elif strategy.eq5_weighted:
             with prof_mod.phase("fl.eq5"):
                 w, denom = agg.unit_weights(selection, data_sizes)
                 frac = w / jnp.where(denom > 0, denom, 1.0)[None, :]  # (K,U)
@@ -1067,6 +1117,10 @@ def _run_meta(flcfg: FLConfig, *, driver: str, umap: UnitMap, params: Pytree,
             "units": list(umap.names),
             # share of Eq. 3's bytes the divergence kernel reads in place
             "eq3_in_place_share": umap.in_place_share(params),
+            # times the scan round trains each client (None: vmap round)
+            "scan_client_passes": (
+                None if flcfg.mode == "vmap"
+                else scan_client_passes(make_strategy(flcfg))),
             "unit_bytes": [float(b) for b in np.asarray(umap.unit_bytes)]}
 
 

@@ -106,6 +106,13 @@ the engines):
   matrix, so the engines may execute it as a streaming accumulation
   (scan) or a fused-psum partial reduction (mesh). Set it to ``False``
   whenever :meth:`aggregate` is overridden with different math.
+- ``selects_topn_divergence`` — an engine-dispatch flag, not a user
+  knob: the selection is exactly Eq. 4's per-unit top-n of the Eq. 3
+  scores (``selection.topn_divergence``, ties to the lower client). With
+  ``needs_divergence`` and ``eq5_weighted`` it lets the scan engine keep
+  each unit's n most divergent locals as the clients train and train
+  each client once (``server.scan_client_passes``). A subclass that
+  overrides :meth:`select` or :meth:`select_with_state` must clear it.
 
 Register with :func:`register_strategy`; ``FLConfig(algo=<name>)`` then
 resolves through the registry, and the name shows up in
@@ -142,6 +149,9 @@ class FLStrategy:
     supports_quantize: bool = True
     eq5_weighted: bool = True
     # ---- engine dispatch flags ----
+    # select() is Eq. 4's per-unit top-n of the divergences (see the
+    # module docstring; clear it when overriding select)
+    selects_topn_divergence: bool = False
     transforms_upload: bool = False
     tracks_residuals: bool = False
     # packed wire-format uplink: the engines route the whole

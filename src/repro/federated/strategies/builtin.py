@@ -53,6 +53,7 @@ class FedLDF(FLStrategy):
     (Eq. 4), Eq. 5 aggregation, divergence-feedback uplink accounted."""
 
     needs_divergence = True
+    selects_topn_divergence = True   # subclasses overriding select clear it
 
     def select(self, divs, key, k, u, n):
         return sel.topn_divergence(divs, n)

@@ -14,8 +14,9 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 instruction (per-device view), scaled by chips for the global numerator.
 
 MODEL_FLOPS (6·N·tokens dense / 6·N_active·tokens MoE; 2·N for inference)
-gives the useful-compute ratio — for FedLDF's two-phase recompute mode this
-correctly reports ≈0.5, surfacing the protocol-level rematerialization cost.
+gives the useful-compute ratio — for the scan round's two-pass recompute
+(FedLAMA) this correctly reports ≈0.5, surfacing the protocol-level
+rematerialization cost.
 """
 from __future__ import annotations
 

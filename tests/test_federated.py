@@ -5,11 +5,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import aggregation as agg
+from repro.core import selection as sel
 from repro.core.units import UnitMap
 from repro.data import (FederatedData, dirichlet_partition, iid_partition,
                         make_image_dataset)
 from repro.federated import FLConfig, build_round_fn, run_training
+from repro.federated.client import make_local_update
 from repro.models import cnn
+from repro.models import transformer as tfm
+from repro.models.config import ModelConfig
+from repro.models.lora import inject_lora, lora_partition
+from repro.optim import sgd
 
 CFG = cnn.VGGConfig().reduced()
 
@@ -43,6 +50,147 @@ def test_vmap_scan_equivalence(setup, algo):
         np.testing.assert_allclose(a, b, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(mv["selection"]),
                                   np.asarray(ms["selection"]))
+
+
+@pytest.fixture(scope="module")
+def lora_setup():
+    """A two-layer transformer under LoRA: the round trains the adapters
+    (stacked ``blocks`` leaves, one unit per layer) over a frozen base."""
+    cfg = ModelConfig(name="tiny", family="dense", d_model=32, num_layers=2,
+                      num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=64,
+                      param_dtype="float32", compute_dtype="float32")
+    full = inject_lora(jax.random.PRNGKey(1),
+                       tfm.init_params(jax.random.PRNGKey(0), cfg), rank=4)
+    part = lora_partition(full)
+    params, frozen = part.split(full)
+    k = 5
+    toks = jax.random.randint(jax.random.PRNGKey(4), (k, 2, 9), 0, 64)
+    batch = {"tokens": toks[..., :-1], "labels": toks[..., 1:]}
+    sizes = jnp.array([12.0, 7.0, 30.0, 9.0, 21.0])
+    return dict(params=params, umap=UnitMap.build(params), batch=batch,
+                sizes=sizes, key=jax.random.PRNGKey(5), k=k,
+                loss=tfm.make_lm_loss(cfg), partition=part, frozen=frozen,
+                lr=0.5)
+
+
+@pytest.fixture(scope="module")
+def vgg_setup(setup):
+    params, umap, batch, sizes, key, k = setup
+    return dict(params=params, umap=umap, batch=batch, sizes=sizes, key=key,
+                k=k, loss=_loss, partition=None, frozen=None, lr=0.01)
+
+
+def _fedldf_round(c, mode, n, batch=None):
+    fl = FLConfig(algo="fedldf", clients_per_round=c["k"], top_n=n,
+                  mode=mode, lr=c["lr"], partition=c["partition"])
+    return jax.jit(build_round_fn(c["loss"], c["umap"], fl))(
+        c["params"], c["batch"] if batch is None else batch, c["sizes"],
+        c["key"], None, c["frozen"])
+
+
+def _client_locals(c, batch=None):
+    """Each client's local model, one client at a time as the scan round
+    trains them."""
+    lu = make_local_update(c["loss"], sgd(c["lr"]),
+                           partition=c["partition"])
+    one = ((lambda b: lu(c["params"], b)[0]) if c["frozen"] is None
+           else (lambda b: lu(c["params"], b, c["frozen"])[0]))
+    return jax.jit(lambda bs: jax.lax.map(one, bs))(
+        c["batch"] if batch is None else batch)
+
+
+def _assert_close_rel(got, want, rtol=1e-6):
+    """Float32 agreement relative to the model: each element within
+    ``rtol`` of its value plus ``rtol`` of the model's largest (a leaf
+    that barely moves, such as a bias whose gradient cancels, holds
+    rounding noise alone)."""
+    scale = max(float(jnp.abs(b).max()) for b in jax.tree.leaves(want))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=rtol,
+                                   atol=rtol * scale)
+
+
+@pytest.mark.parametrize("n", [1, 2, "K"])
+@pytest.mark.parametrize("model", ["vgg", "lora"])
+def test_scan_topn_round_matches_vmap_and_stacked(request, model, n):
+    """FedLDF's scan round trains each client once and keeps each unit's
+    top-n locals as they train: its model equals Eq. 5 over the stacked
+    locals (``aggregate_stacked``) and the vmap round's to float32
+    rounding, and its selection, uplink accounting and loss are the vmap
+    round's."""
+    c = request.getfixturevalue(f"{model}_setup")
+    n = c["k"] if n == "K" else n
+    pv, mv = _fedldf_round(c, "vmap", n)
+    ps, ms = _fedldf_round(c, "scan", n)
+    np.testing.assert_array_equal(np.asarray(ms["selection"]),
+                                  np.asarray(mv["selection"]))
+    assert np.asarray(ms["selection"]).sum(0).tolist() == \
+        [n] * c["umap"].num_units
+    for name in mv["comm"]:
+        assert float(ms["comm"][name]) == float(mv["comm"][name]), name
+    np.testing.assert_allclose(float(ms["loss"]), float(mv["loss"]),
+                               rtol=1e-6)
+    want = agg.aggregate_stacked(_client_locals(c), c["umap"],
+                                 ms["selection"], c["sizes"],
+                                 fallback=c["params"])
+    _assert_close_rel(ps, want)
+    _assert_close_rel(ps, pv)
+    moved = max(float(jnp.abs(a - b).max()) for a, b in
+                zip(jax.tree.leaves(ps), jax.tree.leaves(c["params"])))
+    assert moved > 1e-4
+
+
+@pytest.mark.parametrize("n", [1, 2])
+@pytest.mark.parametrize("model", ["vgg", "lora"])
+def test_scan_topn_tie_keeps_lower_client(request, model, n):
+    """Every client trains on client 0's batch, so each unit's Eq. 3
+    scores tie across all K and ``lax.top_k`` selects clients 0..n-1. The
+    kept slots must hold those same clients: a slot holding another would
+    carry a zero weight (the data sizes differ) and the model would not
+    be client 0's local."""
+    c = request.getfixturevalue(f"{model}_setup")
+    same = jax.tree.map(lambda b: jnp.broadcast_to(b[:1], b.shape),
+                        c["batch"])
+    ps, ms = _fedldf_round(c, "scan", n, batch=same)
+    want = np.zeros((c["k"], c["umap"].num_units))
+    want[:n] = 1
+    np.testing.assert_array_equal(np.asarray(ms["selection"]), want)
+    local = jax.tree.map(lambda l: l[0], _client_locals(c, same))
+    _assert_close_rel(ps, local)
+
+
+@pytest.mark.parametrize("n", [1, 2, 6])
+def test_topn_insert_matches_lax_top_k(n):
+    """The running per-unit insert, client by client, holds the clients
+    and the order ``lax.top_k`` gives over all scores, ties to the lower
+    client, on stacked and plain leaves; the slot reduce is Eq. 5."""
+    tree = {"blocks": {"w": jnp.zeros((3, 2, 5))}, "head": jnp.zeros((4,))}
+    umap = UnitMap.build(tree)
+    k = 6
+    # scores with ties: a unit's 6 clients share 3 values
+    divs = jax.random.randint(jax.random.PRNGKey(0), (k, umap.num_units),
+                              0, 3).astype(jnp.float32)
+    locals_ = jax.tree.map(
+        lambda l: (jnp.arange(k, dtype=l.dtype).reshape((k,) + (1,) * l.ndim)
+                   + 1.0) * jnp.ones(l.shape, l.dtype), tree)
+    shape = (n, umap.num_units)
+    slots = [jax.tree.map(jnp.zeros_like, tree)] * n
+    score = jnp.full(shape, -jnp.inf)
+    who = jnp.zeros(shape, jnp.int32)
+    for i in range(k):
+        slots, score, who = agg.topn_insert(
+            slots, score, who, jax.tree.map(lambda l: l[i], locals_),
+            divs[i], jnp.int32(i), umap)
+    vals, idx = jax.lax.top_k(divs.T, n)
+    np.testing.assert_array_equal(np.asarray(who), np.asarray(idx).T)
+    np.testing.assert_array_equal(np.asarray(score), np.asarray(vals).T)
+    selection = sel.topn_divergence(divs, n)
+    sizes = jnp.array([3.0, 5.0, 1.0, 4.0, 2.0, 6.0])
+    fallback = jax.tree.map(lambda l: l - 1.0, tree)
+    got = agg.topn_aggregate(slots, who, umap, selection, sizes, fallback)
+    want = agg.aggregate_stacked(locals_, umap, selection, sizes,
+                                 fallback=fallback)
+    _assert_close_rel(got, want)
 
 
 def test_fedldf_nK_equals_fedavg(setup):

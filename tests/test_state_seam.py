@@ -310,6 +310,26 @@ def test_fedlama_drivers_agree(task):
                                                   rel=1e-6)
 
 
+def test_fedlama_scan_round_keeps_its_trajectory(task):
+    """FedLAMA selects from every client's score and its interval state,
+    so the scan round still trains each client twice (Eq. 3, then Eq. 5):
+    the trajectory is the one recorded before the FedLDF scan round took
+    one pass (losses, each leaf's absolute sum, uplink bytes)."""
+    params, data = task
+    p, log = run_training_scan(params, _loss, data,
+                               _cfg("fedlama", mode="scan", fedlama_tau=2,
+                                    fedlama_lam=2), rounds=4, seed=0)
+    np.testing.assert_allclose(
+        log.losses, [2.3273391723632812, 2.0734736919403076,
+                     1.9525597095489502, 2.060070037841797], rtol=1e-6)
+    abs_sums = {"head": {"b": 0.019118035037536174, "w": 12.728678405052051},
+                "l1": {"b": 0.008434971852693707, "w": 789.0554684412755}}
+    for got, want in zip(jax.tree.leaves(p), jax.tree.leaves(abs_sums)):
+        assert float(np.abs(np.asarray(got, np.float64)).sum()) == \
+            pytest.approx(want, rel=1e-6)
+    assert log.meter.uplink_bytes == 792256.0
+
+
 def test_fedlama_quantized_composition(task):
     """FedLAMA under the quantize wrapper: interval state and the upload
     transform compose (state flows through QuantizedUpload delegation)."""

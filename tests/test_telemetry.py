@@ -246,6 +246,24 @@ def test_run_meta_records_eq3_in_place_share(task, tmp_path, driver):
     assert meta["eq3_in_place_share"] == pytest.approx(want)
 
 
+@pytest.mark.parametrize("algo,mode,want", [("fedldf", "scan", 1),
+                                             ("fedlama", "scan", 2),
+                                             ("fedldf", "vmap", None)])
+def test_run_meta_records_scan_client_passes(task, tmp_path, algo, mode,
+                                             want):
+    """The run header says how many times the scan round trains each
+    client: once for FedLDF (each unit's top-n kept as clients train),
+    twice for FedLAMA (Eq. 5 recomputes each local), null in vmap mode."""
+    params, data = task
+    lp = str(tmp_path / "ledger.jsonl")
+    run_training_scan(params, _loss, data,
+                      _cfg(algo, mode=mode,
+                           telemetry=TelemetryConfig(ledger_path=lp)),
+                      rounds=1, seed=0)
+    meta = split_runs(read_ledger(lp))[0]["meta"]
+    assert meta["scan_client_passes"] == want
+
+
 @pytest.mark.parametrize("algo", ["fedlama", "fedavg"])
 @pytest.mark.parametrize("driver", ["host", "scan"])
 def test_ledger_resume_contiguous(task, tmp_path, algo, driver):

@@ -271,6 +271,70 @@ def test_grouped_matmul_compiles_for_v5e(compile_for_chip, clients):
     assert "ragged" not in text
 
 
+def _v2lite_scan_round(compile_for_chip, monkeypatch, algo):
+    """The LoRA scan round at DeepSeek-V2-Lite's widths, as the benchmark
+    cell runs it (K=4, top-2, one sequence a client, rank 16 on the
+    cell's projections, checkpointed layers), cut to the dense layer and
+    one MoE layer and to 512 tokens: the MoE layers are one scanned body,
+    so the round's text holds each client pass's kernels once at any
+    depth. Returns the compiled text."""
+    import dataclasses
+
+    from repro.configs import deepseek_v2_lite
+    from repro.core import UnitMap
+    from repro.federated import FLConfig, make_strategy
+    from repro.federated.server import build_round_scan
+    from repro.kernels import ops
+    from repro.models import lora, transformer
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    # the grouped matmul takes megablox where the backend is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(deepseek_v2_lite.config(), num_layers=2,
+                              remat_blocks=True)
+    key = jax.random.PRNGKey(0)
+    full = jax.eval_shape(lambda: lora.inject_lora(
+        key, transformer.init_params(key, cfg), rank=16))
+    part = lora.lora_partition(full)
+    train, frozen = part.split(full)
+    k, t = 4, 512
+    fl = FLConfig(algo=algo, num_clients=20, clients_per_round=k, top_n=2,
+                  mode="scan", partition=part)
+    round_fn = build_round_scan(transformer.make_lm_loss(cfg),
+                                UnitMap.build(train), fl)
+    state = jax.eval_shape(lambda: make_strategy(fl).init_state(train, 20))
+    toks = jax.ShapeDtypeStruct((k, 1, t), jnp.int32)
+    args = (train, {"tokens": toks, "labels": toks},
+            jax.ShapeDtypeStruct((k,), f32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32), state, frozen)
+    leaves, tdef = jax.tree.flatten(args)
+    return compile_for_chip(
+        f"v2lite_scan_round_{algo}",
+        lambda *xs: round_fn(*jax.tree.unflatten(tdef, xs)),
+        *[(l.shape, l.dtype) for l in leaves]).as_text()
+
+
+def _grouped_kernels(text):
+    return [k for k in re.findall(r"%(\S+) = \S+ custom-call\([^)]*\)"
+                                  r"[^\n]*custom_call_target="
+                                  r"\"tpu_custom_call\"", text)
+            if "gmm" in k]
+
+
+def test_v2lite_fedldf_scan_round_trains_each_client_once(compile_for_chip,
+                                                         monkeypatch):
+    """FedLDF's scan round keeps each unit's top-2 locals as the clients
+    train: one client loop, 33 grouped-matmul kernels (the parent's
+    recompute pass made 66); FedLAMA, which selects from every client's
+    score, keeps its two loops and twice the kernels."""
+    once = _grouped_kernels(_v2lite_scan_round(compile_for_chip,
+                                               monkeypatch, "fedldf"))
+    twice = _grouped_kernels(_v2lite_scan_round(compile_for_chip,
+                                                monkeypatch, "fedlama"))
+    assert len(once) == 33, once
+    assert len(twice) == 2 * len(once), twice
+
+
 # ----------------------------------------------------------------------
 # the persistent compilation cache: a fixed path, or the one JAX was given
 # ----------------------------------------------------------------------
