@@ -14,12 +14,14 @@ Two execution layouts are supported:
   Eq. 5 reduces the slots once selection is known, so no client trains
   twice.
 
-The stacked layout additionally supports a *client-sharded* reduction
-(``aggregate_stacked(..., axis_name='clients')`` inside ``shard_map``): each
-device pre-reduces its local clients, then numerators and denominators are
-``psum``'d across the mesh so every device holds the same new global model.
+The stacked layout aggregates in two halves, additive partials
+(:func:`stacked_psum_parts`) and their epilogue
+(:func:`stacked_psum_finalize`), so a *client-sharded* round puts one
+``psum`` between them: each device pre-reduces its local clients, then
+numerators and denominators are ``psum``'d across the mesh so every device
+holds the same new global model.
 
-Both produce bitwise-identical semantics: Eq. 5
+All layouts compute Eq. 5
 ``Ĝ_u = Σ_k s[k,u]·w_k·Θ_{k,u} / Σ_m s[m,u]·w_m``.
 
 With ``s ≡ 1`` this is exactly FedAvg (Eq. 1) — tested as the n=K degeneracy
@@ -59,66 +61,34 @@ def aggregate_stacked(stacked_params: Pytree, umap: UnitMap,
     whose denominator is zero (cannot happen with top-n selection, which
     guarantees n ≥ 1 clients per unit, but can with dropout-style policies).
 
-    ``axis_name`` turns this into the cross-device reduction of a
-    client-sharded round (``shard_map`` over a ``'clients'`` mesh axis):
-    inputs are then the *local* shard — ``selection``/``data_sizes`` rows and
-    stacked leaves for this device's K/D clients. Each device pre-reduces
-    its own clients *unnormalised* (Σ_k s·w_k·Θ_k), then the numerators of
-    every leaf AND the Eq. 5 denominator travel in **one fused psum** (a
-    pytree collective) — one cross-device rendezvous per round instead of
-    one per parameter leaf, which is what makes the sharded round scale on
-    oversubscribed CPU meshes as well as real accelerator fabrics. The
-    division by Σ_m s·w_m happens after the collective, so the math matches
-    the unsharded call up to fp32 summation/normalisation order — hence the
-    sharded-vs-unsharded trajectory tests use a tight fp32 tolerance rather
-    than bit equality.
+    It is :func:`stacked_psum_parts` then :func:`stacked_psum_finalize`,
+    the two halves every round aggregates through. ``axis_name`` puts a
+    ``psum`` between them: the cross-device reduction of a client-sharded
+    round (``shard_map`` over a ``'clients'`` mesh axis), where the inputs
+    are the *local* shard — ``selection``/``data_sizes`` rows and stacked
+    leaves for this device's K/D clients. The numerators of every leaf
+    AND the Eq. 5 denominator then travel in **one fused psum** (a pytree
+    collective) — one cross-device rendezvous per round instead of one
+    per parameter leaf. The division by Σ_m s·w_m happens after the
+    collective, so the sharded result matches the unsharded call up to
+    fp32 summation order.
     """
+    parts, denom = stacked_psum_parts(stacked_params, umap, selection,
+                                      data_sizes)
     if axis_name is not None:
-        return _aggregate_stacked_psum(stacked_params, umap, selection,
-                                       data_sizes, fallback, axis_name)
-    w, denom = unit_weights(selection, data_sizes)          # (K,U), (U,)
-    safe = jnp.where(denom > 0, denom, 1.0)
-    frac = w / safe[None, :]                                # (K, U)
-
-    k = selection.shape[0]
-
-    def agg_one(key: str):
-        off, n = umap.spans[key]
-        seg = jax.lax.dynamic_slice(frac, (0, off), (k, n))  # (K, n)
-        seg_d = jax.lax.dynamic_slice(denom, (off,), (n,))   # (n,)
-
-        def combine(leaf, fb):
-            # leaf: (K, n, ...) for stacked units, (K, ...) otherwise.
-            if n > 1:
-                wx = seg.reshape((k, n) + (1,) * (leaf.ndim - 2))
-            else:
-                wx = seg.reshape((k,) + (1,) * (leaf.ndim - 1))
-            out = jnp.sum(leaf.astype(jnp.float32) * wx, axis=0)
-            if fb is not None:
-                if n > 1:
-                    alive = (seg_d > 0).reshape((n,) + (1,) * (out.ndim - 1))
-                else:
-                    alive = seg_d[0] > 0
-                out = jnp.where(alive, out, fb.astype(jnp.float32))
-            return out.astype(leaf.dtype)
-
-        fsub = fallback[key] if fallback is not None else None
-        if fsub is None:
-            return jax.tree.map(lambda l: combine(l, None),
-                                stacked_params[key])
-        return jax.tree.map(combine, stacked_params[key], fsub)
-
-    return {key: agg_one(key) for key in stacked_params}
+        parts, denom = jax.lax.psum((parts, denom), axis_name)
+    return stacked_psum_finalize(parts, denom, umap, stacked_params,
+                                 fallback)
 
 
 def stacked_psum_parts(stacked_params: Pytree, umap: UnitMap,
                        selection: jnp.ndarray, data_sizes: jnp.ndarray
                        ) -> tuple[Pytree, jnp.ndarray]:
-    """Device-local half of the client-sharded Eq. 5: unnormalised
-    numerators (Σ_k s·w_k·Θ_k per leaf, fp32) and the local denominator
-    rows' contribution (U,). Both are *additive* across the mesh axis, so
-    the caller can fold them — together with any other additive per-round
-    stats (loss sums, comm bytes) — into one fused ``psum``, then call
+    """First half of Eq. 5: unnormalised numerators (Σ_k s·w_k·Θ_k per
+    leaf, fp32) and the stacked rows' denominator contribution (U,). Both
+    are *additive* over clients, so on a mesh the caller can fold them —
+    together with any other additive per-round stats (loss sums, tap
+    partials) — into one fused ``psum``, then call
     :func:`stacked_psum_finalize` on the reduced values. On a 2-D
     ('clients', 'model') mesh the caller may slice each numerator leaf down
     to its 'model'-axis shard *before* the psum (the reduction runs over
@@ -147,7 +117,7 @@ def stacked_psum_parts(stacked_params: Pytree, umap: UnitMap,
 def stacked_psum_finalize(partials: Pytree, denom: jnp.ndarray,
                           umap: UnitMap, stacked_params: Pytree,
                           fallback: Pytree | None) -> Pytree:
-    """Replicated epilogue of the client-sharded Eq. 5: divide the psum'd
+    """Second half of Eq. 5 (replicated on a mesh): divide the summed
     numerators by the global denominator, fall back to the previous global
     model for dead units, and cast back to the parameter dtype.
     ``stacked_params`` is only consulted for leaf dtypes (its leaves need
@@ -179,20 +149,6 @@ def stacked_psum_finalize(partials: Pytree, denom: jnp.ndarray,
         return jax.tree.map(fin, partials[key], stacked_params[key], fsub)
 
     return {key: finalize_one(key) for key in stacked_params}
-
-
-def _aggregate_stacked_psum(stacked_params: Pytree, umap: UnitMap,
-                            selection: jnp.ndarray, data_sizes: jnp.ndarray,
-                            fallback: Pytree | None,
-                            axis_name: str) -> Pytree:
-    """Client-sharded Eq. 5 (see :func:`aggregate_stacked`): local
-    unnormalised partial sums, one fused (numerators, denominator) psum,
-    then the division/fallback epilogue replicated on every device."""
-    partials, denom_loc = stacked_psum_parts(stacked_params, umap,
-                                             selection, data_sizes)
-    partials, denom = jax.lax.psum((partials, denom_loc), axis_name)
-    return stacked_psum_finalize(partials, denom, umap, stacked_params,
-                                 fallback)
 
 
 def hierarchical_psum(tree: Pytree, axis_name: str, axis_size: int,

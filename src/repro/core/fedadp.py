@@ -45,37 +45,16 @@ def neuron_masks(client_params: Pytree, global_params: Pytree,
     return jax.tree.map(mask_leaf, client_params, global_params)
 
 
-def aggregate_fedadp(stacked_params: Pytree, global_params: Pytree,
-                     data_sizes: jnp.ndarray, keep_frac: float) -> Pytree:
-    """Element-wise masked aggregation over the client axis.
-
-    stacked_params: leaves (K, ...). Falls back to the previous global value
-    where no client uploaded an entry.
-    """
-    masks = jax.vmap(lambda p: neuron_masks(p, global_params, keep_frac))(
-        stacked_params)
-    w = data_sizes.astype(jnp.float32)
-
-    def combine(theta, m, g):
-        wx = w.reshape((-1,) + (1,) * (theta.ndim - 1))
-        numer = jnp.sum(theta.astype(jnp.float32) * m * wx, axis=0)
-        denom = jnp.sum(m * wx, axis=0)
-        agg = jnp.where(denom > 0, numer / jnp.where(denom > 0, denom, 1.0),
-                        g.astype(jnp.float32))
-        return agg.astype(g.dtype)
-
-    return jax.tree.map(combine, stacked_params, masks, global_params)
-
-
 def fedadp_psum_parts(stacked_params: Pytree, global_params: Pytree,
                       data_sizes: jnp.ndarray,
                       keep_frac: float) -> tuple[Pytree, Pytree]:
-    """Local halves of :func:`aggregate_fedadp` for the mesh engine's fused
-    per-round psum: masked numerators ``Σ_k θ·m·w`` and element-wise
-    denominators ``Σ_k m·w`` over this device's local client stack. Both
-    are additive over the client axis, so psum-ing the per-device partials
-    and dividing reproduces the single-device aggregation (up to fp32
-    reduction order). The denominator is a param-structured tree — the
+    """Element-wise masked aggregation over the client axis, first half:
+    masked numerators ``Σ_k θ·m·w`` and element-wise denominators
+    ``Σ_k m·w`` over the stacked clients (leaves ``(K, ...)``; this
+    device's clients on a mesh). Both are additive over the client axis,
+    so a mesh psums the per-device partials before
+    :func:`fedadp_psum_finalize`, and matches one device up to fp32
+    reduction order. The denominator is a param-structured tree — the
     engine shards it alongside the numerators on 2-D meshes."""
     masks = jax.vmap(lambda p: neuron_masks(p, global_params, keep_frac))(
         stacked_params)
@@ -96,8 +75,8 @@ def fedadp_psum_parts(stacked_params: Pytree, global_params: Pytree,
 
 def fedadp_psum_finalize(numer: Pytree, denom: Pytree,
                          global_params: Pytree) -> Pytree:
-    """Replicated epilogue: element-wise division with fallback to the
-    previous global value where no client uploaded an entry. Element-wise,
+    """Second half: element-wise division with fallback to the previous
+    global value where no client uploaded an entry. Element-wise,
     so it is shard-safe (runs on 1/M 'model'-axis slices unchanged)."""
 
     def combine(n, d, g):

@@ -1,16 +1,27 @@
 """ServerExecute (paper Algorithm 1) — round function builders + drivers.
 
-Two per-round execution modes produce identical aggregation semantics
+Two per-round execution modes produce the same aggregation semantics
 (tested):
 
-- ``vmap``: all K clients train in parallel (client axis shardable over the
-  'data' mesh axis) and their models are materialised stacked — the paper's
-  own regime (small models, many clients).
-- ``scan``: clients run sequentially over the whole mesh; FedLDF divergence
-  feedback needs all K divergence vectors *before* deciding what to
-  aggregate, so the round runs two passes of deterministic local training
-  (phase 1: divergence only; phase 2: accumulate selected layers). This is
-  protocol-level rematerialization — O(1)-client memory for LLM-scale FL.
+- ``vmap`` (:func:`build_round_vmap`): all K clients train in parallel
+  and their models are materialised stacked — the paper's own regime
+  (small models, many clients). One body serves one device and a mesh:
+  with ``FLConfig(mesh=make_client_mesh(...))`` it runs under
+  ``shard_map`` (each device trains K/D clients; 2-D meshes also shard
+  every leaf 1/M over 'model'), and off a mesh its three collectives —
+  the divergence all-gather, the local-rows slice and the fused psum —
+  are identities. Mesh and single-device trajectories agree to fp32
+  tolerance (tests/test_shard_engine.py, tests/test_model_axis.py).
+- ``scan`` (:func:`build_round_scan`): clients train sequentially —
+  O(1)-client activation memory for LLM-scale FL. A FedLDF client trains
+  once (each unit keeps its top-n locals as the clients train); FedLAMA,
+  which selects from every client's score, trains each client twice
+  (:func:`scan_client_passes`).
+
+Every round aggregates through the strategy's ``psum_parts`` /
+``psum_finalize`` halves (``uplink_psum_parts`` for a packed uplink) and
+ends in one shared tail: comm accounting, the state transition, the
+telemetry taps.
 
 Two *multi-round* drivers share those round functions:
 
@@ -29,35 +40,19 @@ Two *multi-round* drivers share those round functions:
   seed (tested to fp32 tolerance; see benchmarks/round_engine_bench.py for
   the rounds/sec comparison).
 
-Both drivers scale past one accelerator via mesh sharding: with
-``FLConfig(mesh=make_client_mesh(...))`` the vmap round runs under
-``shard_map`` over the mesh's 'clients' axis — each device trains K/D
-clients, FedLDF's divergence matrix is all-gathered for the global top-n
-selection, and the Eq. 5 aggregation / comm totals are psum-reduced, so the
-new global model comes back replicated. A 2-D
-``make_client_mesh(D, model=M)`` mesh additionally FSDP-shards the memory
-that used to be replicated per device: every parameter leaf and every row
-of the error-feedback residual store (the first memory cliff, at N × model
-size) lives as a 1/M 'model'-axis shard
-(:func:`repro.launch.sharding.fl_param_specs`); the round transiently
-all-gathers the full model for local training and slices the aggregation
-back to shards before the clients-axis psum. ``mesh=None`` (default) is the
-original single-device path, byte-for-byte unchanged, and 1-D client meshes
-are unchanged too. Sharded and unsharded trajectories agree to fp32
-tolerance on a fixed seed (the reduction order differs;
-tests/test_shard_engine.py and tests/test_model_axis.py pin this down).
-
 Algorithms are **strategy plugins** (:mod:`repro.federated.strategies`):
 the engines above are thin execution shells around the jit-safe
 :class:`~repro.federated.strategies.FLStrategy` hooks (``select``,
-``transform_upload``, ``aggregate``, ``comm_profile``, …), and
-``FLConfig.algo`` resolves through the strategy registry — built-ins are
-fedldf (paper), fedavg (Eq. 1), random (per-layer random-n), hdfl (client
-dropout [7]), fedadp (neuron pruning [6]), fedlp (layer-wise probabilistic
-pruning, arXiv:2303.06360); ``register_strategy`` adds user-defined
-schemes without touching this module. Per-strategy capability flags
-(``supports_scan`` / ``supports_mesh`` / ``supports_quantize``) replace
-engine-side special cases and are validated at ``FLConfig`` construction.
+``transform_upload``, ``psum_parts``/``psum_finalize``,
+``comm_profile``, …), and ``FLConfig.algo`` resolves through the strategy
+registry — built-ins are fedldf (paper), fedavg (Eq. 1), random
+(per-layer random-n), hdfl (client dropout [7]), fedadp (neuron pruning
+[6]), fedlp (layer-wise probabilistic pruning, arXiv:2303.06360) and
+fedlama (layer-wise adaptive intervals, arXiv:2110.10302);
+``register_strategy`` adds user-defined schemes without touching this
+module. Per-strategy capability flags (``supports_scan`` /
+``supports_mesh`` / ``supports_quantize``) replace engine-side special
+cases and are validated at ``FLConfig`` construction.
 """
 from __future__ import annotations
 
@@ -456,33 +451,66 @@ def _probe_sums(probe, partition, params, frozen, batch) -> dict:
 # ======================================================================
 # Round builders
 # ======================================================================
-def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
-                              strategy, probe=None):
-    """Mesh-sharded round: ``shard_map`` over ('clients'[, 'model']) axes.
+def _round_tail(strategy, umap: UnitMap, taps_on: bool, loss, selection,
+                divs, state, key, wire=None, tap_sums=None) -> dict:
+    """Every round's tail, on replicated inputs: the comm accounting
+    (priced at the packed payload's per-unit wire bytes when ``wire`` is
+    set), the strategy's state transition, and the telemetry taps over
+    the reduced ``tap_sums`` (client-state sums of squares, probe
+    counters). Returns the round's metrics dict."""
+    with prof_mod.phase("fl.comm"):
+        kw = ({} if wire is None
+              else {"unit_bytes_override": wire["unit_bytes"]})
+        comm = strategy.comm_profile(selection, umap, **kw)
+    metrics = {"loss": loss, "comm": comm, "selection": selection}
+    if state is not None:
+        with prof_mod.phase("fl.state"):
+            state = metrics["state"] = strategy.update_state(
+                state, selection, divs, umap, key=key)
+    if taps_on:
+        tap_sums = tap_sums or {}
+        with prof_mod.phase("fl.taps"):
+            extra = taps_mod.probe_taps(tap_sums.get("probe", {}))
+            if wire is not None:
+                extra.update(wire_unit_bytes=wire["unit_bytes"],
+                             wire_bits=wire["bits"])
+            # a None client_sq has collect() derive the client-state
+            # norms from the rows in ``state`` itself
+            metrics["taps"] = taps_mod.collect(
+                strategy, state, selection, divs, umap,
+                client_sq=tap_sums.get("client_sq"), extra=extra)
+    return metrics
 
-    Every device trains its K/D local clients (vmap over the local stack),
-    then the round is stitched back together with collectives:
+
+def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
+                     opt: Optimizer | None = None):
+    """Round function with stacked clients: one ``vmap`` trains all K.
+
+    One body serves one device and a mesh. Off a mesh (``flcfg.mesh`` is
+    None) it runs as it stands, and its three collectives are
+    identities. With a mesh it runs under ``shard_map`` over the
+    ('clients'[, 'model']) axes: every device trains its K/D clients,
+    and the round is stitched back together with collectives:
 
     - FedLDF divergence feedback: per-device (K/D, U) divergence blocks are
       ``all_gather``'d into the full (K, U) matrix so the top-n selection —
       which needs *all* clients' divergences (Eq. 4) — is computed
       replicated on every device; each device then slices back its own rows.
-    - Aggregation (Eq. 5), the loss sum, and the (additive) comm-byte
-      totals all travel in ONE fused ``psum``: local unnormalised
-      numerators/denominator from
-      :func:`repro.core.aggregation.stacked_psum_parts`, local
-      :func:`repro.core.comm.round_comm` byte counts, one collective, then
-      the replicated division epilogue (``stacked_psum_finalize``) — a
-      single cross-device rendezvous per round instead of one per
-      parameter leaf. (:func:`~repro.core.aggregation.aggregate_stacked`
-      with ``axis_name`` / ``round_comm(axis_name=...)`` offer the same
-      reductions as standalone calls.)
+    - Aggregation (Eq. 5), the loss sum and the taps' additive partials
+      travel in ONE fused ``psum``: the strategy's additive local parts
+      (:meth:`FLStrategy.psum_parts`, or ``uplink_psum_parts`` for a
+      packed uplink), one collective, then the replicated epilogue
+      (:meth:`FLStrategy.psum_finalize`) — a single cross-device
+      rendezvous per round instead of one per parameter leaf. Comm
+      accounting runs on the replicated selection and needs no
+      collective. (:func:`~repro.core.aggregation.aggregate_stacked`
+      with ``axis_name`` offers the same reduction as a standalone call.)
     - Strategy state (the cross-round seam): global entries enter and
       leave replicated — selection and ``update_state`` run on identical
       replicated inputs on every device, so the state trajectory matches
-      the unsharded engines. Client entries (e.g. the EF residual store's
-      rows) stay device-local (spec P('clients', ...) rows); the driver's
-      store scatter handles the store update.
+      the single-device round. Client entries (e.g. the EF residual
+      store's rows) stay device-local (spec P('clients', ...) rows); the
+      driver's store scatter handles the store update.
 
     On a 2-D ('clients', 'model') mesh the round is additionally
     FSDP-sharded: parameter leaves (and EF residual rows) enter and leave
@@ -494,7 +522,7 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
     slice and the at-rest params/store replication cliff disappears along
     with 1/M of the collective payload. Gather/slice are exact data
     movement, so a 2-D trajectory matches the 1-D mesh bit-for-bit and the
-    unsharded path to the usual fp32 psum-order tolerance.
+    single-device round to the usual fp32 psum-order tolerance.
 
     Outputs are replicated (per model column) by construction
     (psum/all_gather/replicated inputs); replication *checking* is
@@ -502,32 +530,46 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
     by the equivalence tests instead (tests/test_shard_engine.py,
     tests/test_model_axis.py).
     """
+    opt = opt or sgd(flcfg.lr)
+    local_update = make_local_update(loss_fn, opt, flcfg.local_steps,
+                                     remat=flcfg.remat,
+                                     partition=flcfg.partition)
+    strategy = make_strategy(flcfg)
+    probe = getattr(loss_fn, "probe", None)
     mesh, ax = flcfg.mesh, CLIENT_AXIS
-    d = client_mesh_size(mesh)
-    m = model_mesh_size(mesh)
+    d = 1 if mesh is None else client_mesh_size(mesh)
+    m = 1 if mesh is None else model_mesh_size(mesh)
     k = flcfg.clients_per_round
-    kloc = k // d
-    tele = flcfg.telemetry
-    taps_on = tele is not None and tele.taps
+    taps_on = flcfg.telemetry is not None and flcfg.telemetry.taps
     # hierarchical two-tier reduce: group-local psum + group-leader ring.
     # gs == 0 (default) or gs == d keeps the single flat psum — reduce_
     # lowers to exactly the pre-tier collective, byte-identical rounds.
     gs = flcfg.agg_group_size
     hier = bool(gs) and gs < d
 
-    def reduce_(vals):
-        if hier:
-            return agg.hierarchical_psum(vals, ax, axis_size=d,
-                                         group_size=gs)
-        return jax.lax.psum(vals, ax)
+    # the round's three collectives; off a mesh each is the identity
+    if mesh is None:
+        gather_ = rows_ = reduce_ = lambda x: x     # noqa: E731
+    else:
+        def gather_(divs_loc):
+            return jax.lax.all_gather(divs_loc, ax, axis=0, tiled=True)
 
-    def body(pspecs, sspecs, fspecs, params, batch, data_sizes, key, state,
-             frozen):
-        # everything in here sees the LOCAL shard: kloc clients per device,
-        # and (2-D mesh) 1/M 'model'-axis blocks of each param/state leaf.
-        # With a partition, ``params`` is the TRAINABLE sub-pytree — the
-        # frozen base is gathered transiently for local training and never
-        # touches the psum or the outputs.
+        def rows_(selection):
+            return local_rows(selection, ax, k // d)
+
+        def reduce_(vals):
+            if hier:
+                return agg.hierarchical_psum(vals, ax, axis_size=d,
+                                             group_size=gs)
+            return jax.lax.psum(vals, ax)
+
+    def body(pspecs, sspecs, fspecs, params, batch, data_sizes, key,
+             state=None, frozen=None):
+        # on a mesh everything in here sees the LOCAL shard: K/D clients
+        # per device, and (2-D mesh) 1/M 'model'-axis blocks of each
+        # param/state leaf. With a partition, ``params`` is the TRAINABLE
+        # sub-pytree — the frozen base broadcasts into every client's
+        # local step and never touches the reduce or the outputs.
         params_shard = params
         if m > 1:
             with prof_mod.phase("fl.collective"):
@@ -536,21 +578,20 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
                     frozen = tree_all_gather(frozen, fspecs, MODEL_AXIS)
                 if state is not None:
                     state = _state_model_gather(state, sspecs)
+        lu = (local_update if frozen is None
+              else lambda p, b: local_update(p, b, frozen))
         with prof_mod.phase("fl.local"):
-            if frozen is None:
-                locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
-                    params, batch)
-            else:
-                locals_, losses = jax.vmap(
-                    lambda p, b: local_update(p, b, frozen),
-                    in_axes=(None, 0))(params, batch)
+            locals_, losses = jax.vmap(lu, in_axes=(None, 0))(params, batch)
 
+        # divergence feedback (Eq. 3) is computed on the TRUE local model —
+        # upload transforms (e.g. quantization) below only affect the
+        # uploaded payload.
         divs = None
         if strategy.needs_divergence:
             with prof_mod.phase("fl.eq3"):
-                divs_loc = umap.divergence_batched(locals_, params)
+                divs = umap.divergence_batched(locals_, params)
             with prof_mod.phase("fl.collective"):
-                divs = jax.lax.all_gather(divs_loc, ax, axis=0, tiled=True)
+                divs = gather_(divs)
         # selection is replicated: divs are all-gathered and global state
         # entries enter replicated (client state rows are device-local and
         # must not drive selection under a mesh — see FLStrategy docs)
@@ -558,39 +599,30 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
             selection = strategy.select_with_state(
                 state, divs, key, k, umap.num_units,
                 flcfg.top_n)                                   # (K, U), repl.
-            sel_loc = local_rows(selection, ax, kloc)
+            sel_loc = rows_(selection)
 
-        # ONE fused cross-device reduction per round: the Eq. 5 numerators/
-        # denominator, the loss sum, and the (additive) comm-byte totals
-        # all ride the same psum — a single rendezvous instead of one per
-        # parameter leaf, which is what keeps the sharded round scaling on
-        # oversubscribed CPU meshes as well as accelerator fabrics. The
-        # psum reduces over 'clients' ONLY: on a 2-D mesh each model
-        # column reduces its own 1/M numerator slice, leaving the 'model'
-        # shards intact. Strategies plug in via psum_parts/psum_finalize
-        # (the two halves of their aggregate()); comm_profile is called on
-        # the LOCAL selection rows, so every field but savings_frac must
-        # be additive over the client axis.
+        # the strategy's additive Eq. 5 parts over this device's clients;
+        # they ride the fused reduce below (one rendezvous per round)
         wire = None
+        res_rows = (state["client"]["residual"]
+                    if strategy.tracks_residuals else None)
         if strategy.packed_upload:
             # packed wire-format uplink: quantize the local client deltas
             # into PackedPayload buffers and reduce them through the fused
-            # dequant+EF+accumulate kernel — the parts stay additive over
-            # the clients axis, so they ride the same fused psum below
-            res_rows = (state["client"]["residual"]
-                        if strategy.tracks_residuals else None)
+            # dequant+EF+accumulate kernel (its accumulate half is scoped
+            # fl.eq5 inside the strategy)
             with prof_mod.phase("fl.uplink"):
                 parts, denom_loc, new_rows, wire = \
                     strategy.uplink_psum_parts(locals_, params, umap,
                                                sel_loc, divs, data_sizes,
                                                res_rows)
-            if strategy.tracks_residuals:
-                state = {**state, "client": {**state["client"],
-                                             "residual": new_rows}}
         else:
+            uploads = locals_
             if strategy.transforms_upload:
-                res_rows = (state["client"]["residual"]
-                            if strategy.tracks_residuals else None)
+                # e.g. quantized deltas: the server reconstructs
+                # Ĝ + dequant(Q(Δ + e)) for uploaded layers; error
+                # feedback residuals update only where a layer was
+                # actually uploaded (s[k,u] = 1)
                 with prof_mod.phase("fl.uplink"):
                     uploads, cand_res = jax.vmap(
                         lambda loc, res: strategy.transform_upload(
@@ -602,14 +634,15 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
                             lambda cand, old, s: strategy.update_residual(
                                 cand, old, s, umap, params),
                             in_axes=(0, 0, 0))(cand_res, res_rows, sel_loc)
-                        state = {**state, "client": {**state["client"],
-                                                     "residual": new_rows}}
-            else:
-                uploads = locals_
             with prof_mod.phase("fl.eq5"):
                 parts, denom_loc = strategy.psum_parts(
                     uploads, umap, sel_loc, data_sizes,
                     global_params=params)
+        if strategy.tracks_residuals:
+            # the residual rows ride the state seam as the client entry
+            # named "residual" (see FLStrategy.init_state)
+            state = {**state, "client": {**state["client"],
+                                         "residual": new_rows}}
         if m > 1:
             with prof_mod.phase("fl.collective"):
                 parts = tree_shard_slice(parts, pspecs, m, MODEL_AXIS)
@@ -621,79 +654,44 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
                         jax.tree.structure(parts):
                     denom_loc = tree_shard_slice(denom_loc, pspecs, m,
                                                  MODEL_AXIS)
-        with prof_mod.phase("fl.comm"):
-            if wire is not None:
-                # charge the packed payload's actual wire bytes (bit-width
-                # vector + headers), not fp32 unit sizes
-                comm_loc = strategy.comm_profile(
-                    sel_loc, umap, unit_bytes_override=wire["unit_bytes"])
-            else:
-                comm_loc = strategy.comm_profile(sel_loc, umap)
-        comm_add = {n_: v for n_, v in comm_loc.items()
-                    if n_ != "savings_frac"}   # byte counts are additive
-        # telemetry taps: the client-state squared-norm partials (EF
-        # residual rows are device-local) ride the SAME fused psum — taps
-        # must not add a second rendezvous. Disabled telemetry keeps the
-        # original 3-tuple, so the compiled round is bit-identical.
-        tap_parts = {}
-        if taps_on and state is not None and state.get("client"):
+        # telemetry partials (client-state sums of squares — EF residual
+        # rows are device-local — and probe counters) ride the SAME
+        # reduce: taps must not add a second rendezvous. Disabled
+        # telemetry adds no leaves, so the compiled round is unchanged.
+        tap_sums = {}
+        if taps_on:
             with prof_mod.phase("fl.taps"):
-                tap_parts["client_sq"] = taps_mod.client_sqsums(
-                    state["client"])
-        if taps_on and probe is not None:
-            with prof_mod.phase("fl.taps"):
-                tap_parts["probe"] = _probe_sums(probe, flcfg.partition,
-                                                 params, frozen, batch)
+                if state is not None and state.get("client"):
+                    tap_sums["client_sq"] = taps_mod.client_sqsums(
+                        state["client"])
+                if probe is not None:
+                    tap_sums["probe"] = _probe_sums(
+                        probe, flcfg.partition, params, frozen, batch)
         with prof_mod.phase("fl.collective"):
-            if tap_parts:
-                (parts, denom), loss_sum, comm, tap_parts = reduce_(
-                    ((parts, denom_loc), losses.sum(), comm_add, tap_parts))
-            else:
-                (parts, denom), loss_sum, comm = reduce_(
-                    ((parts, denom_loc), losses.sum(), comm_add))
+            (parts, denom), loss_sum, tap_sums = reduce_(
+                ((parts, denom_loc), losses.sum(), tap_sums))
         with prof_mod.phase("fl.eq5"):
             new_params = strategy.psum_finalize(parts, denom, umap,
                                                 params_shard, params_shard)
-        with prof_mod.phase("fl.comm"):
-            comm["savings_frac"] = 1.0 - comm["uplink_total"] / \
-                comm["fedavg_uplink"]
+        metrics = _round_tail(strategy, umap, taps_on, loss_sum / k,
+                              selection, divs, state, key, wire, tap_sums)
+        if mesh is not None:
             # per-tier aggregation-traffic split: static topology ×
-            # payload arithmetic added AFTER the reduce (deliberately not
-            # riding the psum, so the flat path's collective payload — and
-            # trajectory — stays byte-identical to the pre-tier engine).
-            # Payload = this device's Eq. 5 numerator tree (1/M slice on a
-            # 2-D mesh).
-            for n_, v in comm_mod.agg_tier_bytes(umap.total_bytes / m, d,
-                                                 gs if hier else 0).items():
-                comm[n_] = jnp.float32(v)
-            loss = loss_sum / k
-        metrics = {"loss": loss, "comm": comm, "selection": selection}
-        if state is not None:
-            # replicated transition: selection/divs/global entries are
-            # identical on every device, so the new global state is too;
+            # payload arithmetic (this device's Eq. 5 numerator tree, a
+            # 1/M slice on a 2-D mesh), not riding the reduce
+            with prof_mod.phase("fl.comm"):
+                for n_, v in comm_mod.agg_tier_bytes(
+                        umap.total_bytes / m, d, gs if hier else 0).items():
+                    metrics["comm"][n_] = jnp.float32(v)
+        if m > 1 and state is not None:
             # client rows go back to this device's 1/M store-row shard
-            with prof_mod.phase("fl.state"):
-                state = strategy.update_state(state, selection, divs, umap,
-                                              key=key)
-        if taps_on:
-            # replicated by construction: selection/divs/global state are
-            # identical everywhere, client norms were just psum'd. The
-            # non-None client_sq stops collect() from re-deriving norms
-            # from the device-local rows.
-            with prof_mod.phase("fl.taps"):
-                metrics["taps"] = taps_mod.collect(
-                    strategy, state, selection, divs, umap,
-                    client_sq=tap_parts.get("client_sq", {}),
-                    extra={**taps_mod.probe_taps(tap_parts.get("probe", {})),
-                           **({} if wire is None else
-                              {"wire_unit_bytes": wire["unit_bytes"],
-                               "wire_bits": wire["bits"]})})
-        if state is not None:
-            if m > 1:
-                with prof_mod.phase("fl.collective"):
-                    state = _state_model_slice(state, sspecs, m)
-            metrics["state"] = state
+            with prof_mod.phase("fl.collective"):
+                metrics["state"] = _state_model_slice(metrics["state"],
+                                                      sspecs, m)
         return new_params, metrics
+
+    if mesh is None:
+        return functools.partial(body, None, None, None)   # no specs
 
     out_metrics_spec = {"loss": P(), "comm": P(), "selection": P()}
     if taps_on:
@@ -722,138 +720,16 @@ def _build_round_vmap_sharded(local_update, umap: UnitMap, flcfg: FLConfig,
             # mesh); it is never part of the outputs
             in_specs.append(fspecs)
             args.append(frozen)
-        has_state, has_frozen = state is not None, frozen is not None
+        names = [n_ for n_, a in (("state", state), ("frozen", frozen))
+                 if a is not None]
 
         def call(p, b, s, key_, *rest):
-            rest = list(rest)
-            st = rest.pop(0) if has_state else None
-            fz = rest.pop(0) if has_frozen else None
-            return body(pspecs, sspecs, fspecs, p, b, s, key_, st, fz)
+            return body(pspecs, sspecs, fspecs, p, b, s, key_,
+                        **dict(zip(names, rest)))
 
         sharded = shard_map_norep(call, mesh, in_specs=tuple(in_specs),
                                   out_specs=(pspecs, out_metrics))
         return sharded(*args)
-
-    return round_fn
-
-
-def build_round_vmap(loss_fn, umap: UnitMap, flcfg: FLConfig,
-                     opt: Optimizer | None = None):
-    """Round function with parallel (stacked) clients.
-
-    With ``flcfg.mesh`` set, the client axis is sharded over the mesh's
-    'clients' axis (every device trains K/D clients; aggregation is a
-    cross-device psum) — same signature, same semantics, fp32-tolerance
-    identical trajectories.
-    """
-    opt = opt or sgd(flcfg.lr)
-    local_update = make_local_update(loss_fn, opt, flcfg.local_steps,
-                                     remat=flcfg.remat,
-                                     partition=flcfg.partition)
-    strategy = make_strategy(flcfg)
-    if flcfg.mesh is not None:
-        return _build_round_vmap_sharded(local_update, umap, flcfg, strategy,
-                                         getattr(loss_fn, "probe", None))
-    k = flcfg.clients_per_round
-    taps_on = flcfg.telemetry is not None and flcfg.telemetry.taps
-    probe = getattr(loss_fn, "probe", None)
-
-    def round_fn(params: Pytree, batch: dict, data_sizes: jnp.ndarray,
-                 key: jax.Array, state: Optional[dict] = None,
-                 frozen: Optional[Pytree] = None):
-        with prof_mod.phase("fl.local"):
-            if frozen is None:
-                locals_, losses = jax.vmap(local_update, in_axes=(None, 0))(
-                    params, batch)
-            else:
-                # partitioned round: ``params`` is the trainable
-                # sub-pytree; the frozen base broadcasts into every
-                # client's local step
-                locals_, losses = jax.vmap(
-                    lambda p, b: local_update(p, b, frozen),
-                    in_axes=(None, 0))(params, batch)
-
-        # divergence feedback (Eq. 3) is computed on the TRUE local model —
-        # upload transforms (e.g. quantization) below only affect the
-        # uploaded payload.
-        divs = None
-        if strategy.needs_divergence:
-            with prof_mod.phase("fl.eq3"):
-                divs = umap.divergence_batched(locals_, params)
-        with prof_mod.phase("fl.eq4"):
-            selection = strategy.select_with_state(
-                state, divs, key, k, umap.num_units, flcfg.top_n)
-
-        wire = None
-        if strategy.packed_upload:
-            # packed wire-format uplink: the strategy quantizes the client
-            # deltas into PackedPayload buffers and reduces them through
-            # the fused dequant+EF+accumulate kernel in one shot (its
-            # accumulate half is scoped fl.eq5 inside the strategy)
-            res_rows = (state["client"]["residual"]
-                        if strategy.tracks_residuals else None)
-            with prof_mod.phase("fl.uplink"):
-                new_params, new_rows, wire = strategy.uplink_round(
-                    locals_, params, umap, selection, divs, data_sizes,
-                    res_rows)
-            if strategy.tracks_residuals:
-                state = {**state, "client": {**state["client"],
-                                             "residual": new_rows}}
-        else:
-            if strategy.transforms_upload:
-                # e.g. quantized deltas: the server reconstructs
-                # Ĝ + dequant(Q(Δ + e)) for uploaded layers; error
-                # feedback residuals update only where a layer was
-                # actually uploaded (s[k,u] = 1). The residual rows ride
-                # the state seam as the client entry named "residual"
-                # (see FLStrategy.init_state).
-                res_rows = (state["client"]["residual"]
-                            if strategy.tracks_residuals else None)
-                with prof_mod.phase("fl.uplink"):
-                    uploads, cand_res = jax.vmap(
-                        lambda loc, res: strategy.transform_upload(
-                            loc, params, umap, res),
-                        in_axes=(0, 0 if res_rows is not None else None),
-                    )(locals_, res_rows)
-                    if strategy.tracks_residuals:
-                        new_rows = jax.vmap(
-                            lambda cand, old, s: strategy.update_residual(
-                                cand, old, s, umap, params),
-                            in_axes=(0, 0, 0))(cand_res, res_rows,
-                                               selection)
-                        state = {**state, "client": {**state["client"],
-                                                     "residual": new_rows}}
-            else:
-                uploads = locals_
-            with prof_mod.phase("fl.eq5"):
-                new_params = strategy.aggregate(uploads, umap, selection,
-                                                data_sizes, params)
-        with prof_mod.phase("fl.comm"):
-            if wire is not None:
-                comm = strategy.comm_profile(
-                    selection, umap, unit_bytes_override=wire["unit_bytes"])
-            else:
-                comm = strategy.comm_profile(selection, umap)
-            metrics = {"loss": losses.mean(), "comm": comm,
-                       "selection": selection}
-        if state is not None:
-            with prof_mod.phase("fl.state"):
-                metrics["state"] = strategy.update_state(
-                    state, selection, divs, umap, key=key)
-        if taps_on:
-            # client rows in the post-update_state view carry the
-            # post-residual-update values (update_state preserves entries
-            # it does not own), matching the mesh engine's tap timing.
-            with prof_mod.phase("fl.taps"):
-                metrics["taps"] = taps_mod.collect(
-                    strategy, metrics.get("state"), selection, divs, umap,
-                    extra={**taps_mod.probe_taps(_probe_sums(
-                               probe, flcfg.partition, params, frozen,
-                               batch)),
-                           **({} if wire is None else
-                              {"wire_unit_bytes": wire["unit_bytes"],
-                               "wire_bits": wire["bits"]})})
-        return new_params, metrics
 
     return round_fn
 
@@ -888,8 +764,9 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
     after selection — at top-2 over bf16 adapters the same bytes as the
     float32 accumulator. Strategies whose aggregation is not an Eq. 5
     weighted mean (e.g. FedADP's element-wise neuron masks) instead have
-    their sequentially-trained locals *stacked* by the scan and fed to the
-    same :meth:`FLStrategy.aggregate` hook used in vmap mode — O(K)
+    their sequentially-trained locals *stacked* by the scan and aggregated
+    through the same :meth:`FLStrategy.psum_parts` /
+    :meth:`FLStrategy.psum_finalize` halves as the vmap round — O(K)
     parameter memory, but still O(1) activation memory, which is the scan
     engine's binding constraint for deep models.
     """
@@ -982,8 +859,9 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
                 new_params = agg.streaming_finalize(acc, umap, denom, params)
         else:
             # ---- phase 2 (non-Eq.5 aggregation, e.g. FedADP): train
-            # sequentially, let the scan stack the locals, and call the
-            # same stacked-clients aggregate hook as the vmap engine.
+            # sequentially, let the scan stack the locals, and aggregate
+            # them through the strategy's two halves as the vmap round
+            # does (with no reduce between them).
             def phase2_stack(carry, batch_k):
                 return carry, lu(params, batch_k)
 
@@ -991,24 +869,21 @@ def build_round_scan(loss_fn, umap: UnitMap, flcfg: FLConfig,
                 _, (stacked, losses2) = jax.lax.scan(phase2_stack, None,
                                                      batch)
             with prof_mod.phase("fl.eq5"):
-                new_params = strategy.aggregate(stacked, umap, selection,
-                                                data_sizes, params)
+                parts, denom = strategy.psum_parts(
+                    stacked, umap, selection, data_sizes,
+                    global_params=params)
+                new_params = strategy.psum_finalize(parts, denom, umap,
+                                                    params, params)
 
-        with prof_mod.phase("fl.comm"):
-            comm = strategy.comm_profile(selection, umap)
-            loss = (losses1 if losses1 is not None else losses2).mean()
-        metrics = {"loss": loss, "comm": comm, "selection": selection}
-        if state is not None:
-            with prof_mod.phase("fl.state"):
-                metrics["state"] = strategy.update_state(
-                    state, selection, divs, umap, key=key)
+        loss = (losses1 if losses1 is not None else losses2).mean()
+        tap_sums = None
         if taps_on:
             with prof_mod.phase("fl.taps"):
-                metrics["taps"] = taps_mod.collect(
-                    strategy, metrics.get("state"), selection, divs, umap,
-                    extra=taps_mod.probe_taps(_probe_sums(
-                        probe, flcfg.partition, params, frozen, batch)))
-        return new_params, metrics
+                tap_sums = {"probe": _probe_sums(probe, flcfg.partition,
+                                                 params, frozen, batch)}
+        return new_params, _round_tail(strategy, umap, taps_on, loss,
+                                       selection, divs, state, key,
+                                       tap_sums=tap_sums)
 
     return round_fn
 

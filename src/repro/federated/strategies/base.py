@@ -1,10 +1,10 @@
 """FLStrategy protocol + registry: pluggable FL algorithms for one engine.
 
 An :class:`FLStrategy` packages everything algorithm-specific about a
-federated round behind a fixed set of **jit-safe hooks**, so the three
-execution shells in :mod:`repro.federated.server` (single-device ``vmap``,
-sequential ``scan``, and the mesh-sharded ``shard_map`` round) share one
-round body instead of re-implementing per-algorithm branches three times.
+federated round behind a fixed set of **jit-safe hooks**, so the two
+round builders in :mod:`repro.federated.server` (stacked clients under
+``vmap``, on one device or a mesh, and sequential clients under ``scan``)
+share one protocol instead of re-implementing per-algorithm branches.
 
 Hook contract (every hook is traced under ``jax.jit`` — no Python control
 flow on traced values, no host callbacks, static shapes only):
@@ -24,35 +24,33 @@ flow on traced values, no host callbacks, static shapes only):
   per-client error-feedback residual update, gated on the selection row
   (residuals advance only where a layer actually shipped). Only consulted
   when :attr:`tracks_residuals` is set.
-- ``aggregate(uploads, umap, selection, data_sizes, global_params,
-  axis_name=None) -> new global params`` — the server-side reduction over
-  client-stacked uploads. The default is the paper's Eq. 5 masked
-  weighted mean (:func:`repro.core.aggregation.aggregate_stacked`).
-- ``psum_parts`` / ``psum_finalize`` — the two halves of the aggregation
-  that the mesh-sharded engine fuses into its single per-round ``psum``
-  (additive local partials, then a replicated epilogue). The defaults
-  implement Eq. 5; a strategy that overrides :meth:`aggregate` must either
-  declare ``supports_mesh = False`` or override these to match.
+- ``psum_parts`` / ``psum_finalize`` — the server-side aggregation, in
+  two halves: additive partials over the client-stacked uploads, then
+  the epilogue on their sums. Every stacked-clients round calls them,
+  with the mesh's fused ``psum`` between them (an identity off a mesh).
+  The defaults implement the paper's Eq. 5 masked weighted mean
+  (:func:`repro.core.aggregation.stacked_psum_parts` /
+  :func:`~repro.core.aggregation.stacked_psum_finalize`). A strategy with
+  other math (FedADP's element-wise neuron masks) overrides both halves,
+  keeps its partials additive over clients, and sets
+  ``eq5_weighted = False``.
 - ``comm_profile(selection, umap, param_bytes_override=None,
   unit_bytes_override=None) -> dict`` — per-round communication
   accounting. Must preserve the ledger invariant
   ``uplink_payload + uplink_feedback == uplink_total`` (tested for every
-  registered strategy). Inside the sharded round it is called on the
-  *local* selection rows and every field except ``savings_frac`` must be
-  additive across devices (the engine psums them and recomputes
-  ``savings_frac``). ``unit_bytes_override`` carries the packed wire
+  registered strategy). Every round calls it once, on the replicated
+  (K, U) selection. ``unit_bytes_override`` carries the packed wire
   format's per-unit byte vector (``PackedPayload.unit_wire_bytes``) and
   takes precedence over the legacy uniform repricing.
-- ``uplink_round`` / ``uplink_psum_parts`` — the packed-uplink fast path,
-  consulted only when :attr:`packed_upload` is set: the strategy turns
-  the stacked client locals directly into a packed wire payload
-  (``core/wire``) and reduces it through the fused dequant+EF+Eq. 5
-  kernel (``kernels/uplink``), never materialising per-client fp32
-  reconstructions. ``uplink_round`` returns the finished global model
-  (single-device round); ``uplink_psum_parts`` returns additive partials
-  for the mesh engine's fused psum, finalized by ``psum_finalize``.
+- ``uplink_psum_parts`` — the packed-uplink path, consulted only when
+  :attr:`packed_upload` is set: the strategy turns the stacked client
+  locals directly into a packed wire payload (``core/wire``) and reduces
+  it through the fused dequant+EF+Eq. 5 kernel (``kernels/uplink``),
+  never materialising per-client fp32 reconstructions. It returns
+  additive partials for the round's reduce, finalized by
+  ``psum_finalize``.
 
-**Cross-round state seam** (optional; all three engines thread it):
+**Cross-round state seam** (optional; every round threads it):
 
 - ``init_state(params, num_clients, mesh=None) -> state | None`` — declare
   cross-round state once before round 0. ``None`` (the default) keeps the
@@ -96,16 +94,16 @@ the engines):
 - ``supports_scan`` — the strategy can run under ``mode="scan"``.
   Strategies with ``eq5_weighted`` stream clients through an O(1)-client
   accumulator; others have their sequentially-trained locals stacked by
-  the scan and fed to the same :meth:`aggregate` hook (O(K) param memory,
-  still O(1) activation memory).
+  the scan and fed to the same ``psum_parts``/``psum_finalize`` halves
+  (O(K) param memory, still O(1) activation memory).
 - ``supports_mesh`` — the strategy can run client-sharded over a device
-  mesh (requires Eq. 5 ``psum_parts``/``psum_finalize`` or overrides).
+  mesh (its ``psum_parts`` must be additive over clients).
 - ``supports_quantize`` — the quantize(+EF) wrapper may be composed on
   top (``FLConfig(compression=CompressionConfig(...))``).
 - ``eq5_weighted`` — aggregation is exactly Eq. 5 over the selection
   matrix, so the engines may execute it as a streaming accumulation
-  (scan) or a fused-psum partial reduction (mesh). Set it to ``False``
-  whenever :meth:`aggregate` is overridden with different math.
+  (scan). Set it to ``False`` whenever ``psum_parts``/``psum_finalize``
+  are overridden with different math.
 - ``selects_topn_divergence`` — an engine-dispatch flag, not a user
   knob: the selection is exactly Eq. 4's per-unit top-n of the Eq. 3
   scores (``selection.topn_divergence``, ties to the lower client). With
@@ -154,9 +152,9 @@ class FLStrategy:
     selects_topn_divergence: bool = False
     transforms_upload: bool = False
     tracks_residuals: bool = False
-    # packed wire-format uplink: the engines route the whole
-    # locals→payload→aggregate reduction through uplink_round /
-    # uplink_psum_parts instead of transform_upload + aggregate
+    # packed wire-format uplink: the round routes the whole
+    # locals→payload→aggregate reduction through uplink_psum_parts
+    # instead of transform_upload + psum_parts
     packed_upload: bool = False
 
     def __init__(self, cfg):
@@ -242,21 +240,14 @@ class FLStrategy:
                         global_params: Pytree) -> Pytree:
         raise NotImplementedError
 
-    def aggregate(self, uploads: Pytree, umap: UnitMap,
-                  selection: jnp.ndarray, data_sizes: jnp.ndarray,
-                  global_params: Pytree,
-                  axis_name: str | None = None) -> Pytree:
-        return agg.aggregate_stacked(uploads, umap, selection, data_sizes,
-                                     fallback=global_params,
-                                     axis_name=axis_name)
-
-    # ---- mesh-sharded halves of aggregate() (fused-psum protocol) ----
+    # ---- aggregation, in two halves (the round's reduce between) ----
     def psum_parts(self, uploads: Pytree, umap: UnitMap,
                    sel_loc: jnp.ndarray, data_sizes: jnp.ndarray,
                    global_params: Optional[Pytree] = None
                    ) -> tuple[Pytree, Pytree]:
-        """Additive local partials for the fused per-round psum. The
-        returned ``parts`` must be param-structured; ``denom`` may be a
+        """Additive partials over the stacked ``uploads`` (this device's
+        clients on a mesh, all K off it), summed by the round's reduce.
+        The returned ``parts`` must be param-structured; ``denom`` may be a
         single ``(U,)`` array (Eq. 5) *or* a param-structured tree of
         element-wise denominators (FedADP) — the engine slices a
         param-structured denom to 'model'-axis shards alongside ``parts``.
@@ -270,21 +261,7 @@ class FLStrategy:
         return agg.stacked_psum_finalize(parts, denom, umap, params_shard,
                                          fallback)
 
-    # ---- packed-uplink fast path (only when packed_upload is set) ----
-    def uplink_round(self, locals_: Pytree, global_params: Pytree,
-                     umap: UnitMap, selection: jnp.ndarray,
-                     divs: Optional[jnp.ndarray], data_sizes: jnp.ndarray,
-                     res_rows: Optional[Pytree]
-                     ) -> tuple[Pytree, Optional[Pytree], dict]:
-        """Single-device packed round: stacked client ``locals_`` →
-        ``(new_global_params, new_residual_rows, wire)`` where ``wire`` is
-        ``{"unit_bytes": (U,), "bits": (U,), "nbytes": int}`` — the packed
-        payload's accounting, fed to :meth:`comm_profile` via
-        ``unit_bytes_override``."""
-        raise NotImplementedError(
-            f"{type(self).__name__} sets packed_upload but does not "
-            "implement uplink_round")
-
+    # ---- packed-uplink path (only when packed_upload is set) ----
     def uplink_psum_parts(self, locals_: Pytree, global_params: Pytree,
                           umap: UnitMap, sel_loc: jnp.ndarray,
                           divs: Optional[jnp.ndarray],
@@ -292,10 +269,12 @@ class FLStrategy:
                           res_rows: Optional[Pytree]
                           ) -> tuple[Pytree, jnp.ndarray,
                                      Optional[Pytree], dict]:
-        """Mesh half of :meth:`uplink_round`: additive Eq. 5 numerator
-        partials + local denominator (for the engine's fused psum, then
-        :meth:`psum_finalize`), plus the local residual rows and wire
-        accounting."""
+        """Stacked client ``locals_`` → ``(parts, denom, new_residual_rows,
+        wire)``: additive Eq. 5 numerator partials and denominator (for
+        the round's reduce, then :meth:`psum_finalize`), the updated
+        residual rows, and ``wire`` = ``{"unit_bytes": (U,), "bits": (U,),
+        "nbytes": int}``, the packed payload's accounting, which prices
+        :meth:`comm_profile` via ``unit_bytes_override``."""
         raise NotImplementedError(
             f"{type(self).__name__} sets packed_upload but does not "
             "implement uplink_psum_parts")

@@ -87,14 +87,13 @@ class HDFL(FLStrategy):
 class FedADP(FLStrategy):
     """FedADP [6]: per-client neuron-granularity pruning with element-wise
     masked aggregation — not an Eq. 5 selection scheme, so it overrides
-    :meth:`aggregate` wholesale. Works in ``vmap`` mode, in ``scan`` mode
-    (the engine stacks the sequentially-trained locals and feeds them to
-    the same hook), and client-sharded over a mesh: its masked numerators
-    ``Σ_k θ·m·w`` and element-wise denominators ``Σ_k m·w`` are additive
-    over clients, so :meth:`psum_parts`/:meth:`psum_finalize` ride the
-    engine's fused per-round psum — the denominator is a param-structured
-    tree (not the Eq. 5 ``(U,)`` vector), which the engine 'model'-axis
-    shards alongside the numerators on 2-D meshes."""
+    both aggregation halves. Its masked numerators ``Σ_k θ·m·w`` and
+    element-wise denominators ``Σ_k m·w`` are additive over clients, so
+    :meth:`psum_parts`/:meth:`psum_finalize` serve ``vmap`` mode, ``scan``
+    mode (the engine stacks the sequentially-trained locals) and the
+    mesh's fused per-round psum alike — the denominator is a
+    param-structured tree (not the Eq. 5 ``(U,)`` vector), which the
+    engine 'model'-axis shards alongside the numerators on 2-D meshes."""
 
     options_cls = FedADPOptions
     eq5_weighted = False        # element-wise masks, not unit weights
@@ -102,18 +101,10 @@ class FedADP(FLStrategy):
 
     def select(self, divs, key, k, u, n):
         # selection is accounting-only for FedADP: pruning happens at
-        # neuron granularity inside aggregate()
+        # neuron granularity inside psum_parts()
         return sel.full_participation(k, u)
 
-    def aggregate(self, uploads, umap, selection, data_sizes,
-                  global_params, axis_name=None):
-        assert axis_name is None, \
-            "the mesh engine uses psum_parts/psum_finalize"
-        return fedadp_mod.aggregate_fedadp(uploads, global_params,
-                                           data_sizes,
-                                           self.opts.keep)
-
-    # ---- mesh halves: per-leaf additive masked partials ----
+    # ---- aggregation halves: per-leaf additive masked partials ----
     def psum_parts(self, uploads, umap, sel_loc, data_sizes,
                    global_params=None):
         assert global_params is not None, \
@@ -166,9 +157,7 @@ class FedLP(FLStrategy):
             selection, umap, divergence_feedback=False,
             param_bytes_override=param_bytes_override,
             unit_bytes_override=unit_bytes_override)
-        # keep-mask header: U bits per participating client, byte-padded.
-        # Additive in the client axis, so the sharded engine's psum over
-        # local rows sums to the global header cost.
+        # keep-mask header: U bits per participating client, byte-padded
         mask_bytes = jnp.float32(selection.shape[0]
                                  * ((umap.num_units + 7) // 8))
         stats["uplink_feedback"] = stats["uplink_feedback"] + mask_bytes

@@ -106,11 +106,6 @@ class QuantizedUpload(FLStrategy):
         # the packed wire bytes via the round's wire accounting.
         return self.inner.telemetry_taps(state, selection, divs, umap)
 
-    def aggregate(self, uploads, umap, selection, data_sizes,
-                  global_params, axis_name=None):
-        return self.inner.aggregate(uploads, umap, selection, data_sizes,
-                                    global_params, axis_name=axis_name)
-
     def psum_parts(self, uploads, umap, sel_loc, data_sizes,
                    global_params=None):
         return self.inner.psum_parts(uploads, umap, sel_loc, data_sizes,
@@ -123,8 +118,8 @@ class QuantizedUpload(FLStrategy):
     # ==================================================================
     # Packed wire-format path (CompressionConfig.fused)
     # ==================================================================
-    def _packed_reduce(self, locals_, global_params, umap, sel_rows, divs,
-                       data_sizes, res_rows):
+    def uplink_psum_parts(self, locals_, global_params, umap, sel_rows,
+                          divs, data_sizes, res_rows):
         """Stacked locals → packed payload → fused kernel reduction.
 
         Returns ``(num_parts, denom, new_res_rows, wire)`` where
@@ -132,8 +127,8 @@ class QuantizedUpload(FLStrategy):
         ``Σ_k w[k,u]·Θ̂_k = denom_u·Ĝ + Σ_k w·scale·levels`` (the second
         term via the fused uplink kernel), ``denom`` the ``(U,)`` local
         weight sums, and ``wire`` the payload's byte accounting. Additive
-        over mesh client shards, so the mesh engine psums the parts
-        exactly like the legacy ``psum_parts`` output.
+        over clients, so the round reduces the parts exactly like the
+        legacy ``psum_parts`` output, then calls :meth:`psum_finalize`.
         """
         comp = self.comp
         k = sel_rows.shape[0]
@@ -221,21 +216,6 @@ class QuantizedUpload(FLStrategy):
         wire = {"unit_bytes": payload.unit_wire_bytes(umap),
                 "bits": bits, "nbytes": payload.nbytes}
         return num_parts, denom, res_parts, wire
-
-    def uplink_round(self, locals_, global_params, umap, selection, divs,
-                     data_sizes, res_rows):
-        parts, denom, new_rows, wire = self._packed_reduce(
-            locals_, global_params, umap, selection, divs, data_sizes,
-            res_rows)
-        with prof_mod.phase("fl.eq5"):
-            new_params = self.psum_finalize(parts, denom, umap,
-                                            global_params, global_params)
-        return new_params, new_rows, wire
-
-    def uplink_psum_parts(self, locals_, global_params, umap, sel_loc,
-                          divs, data_sizes, res_rows):
-        return self._packed_reduce(locals_, global_params, umap, sel_loc,
-                                   divs, data_sizes, res_rows)
 
     # ==================================================================
     # Legacy unfused chain (CompressionConfig.fused=False)

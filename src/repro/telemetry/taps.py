@@ -7,11 +7,11 @@ hooks every strategy already implements. The helpers here add what only
 the engines can see:
 
 - :func:`client_sqsums` — squared-norm partials of the round's *client*
-  state rows (e.g. the participants' error-feedback residuals). Under the
-  mesh-sharded round the rows are device-local, so the engine computes
-  these partials locally and rides them on the round's single fused
-  ``psum`` (no extra rendezvous, no host sync); the unsharded engines sum
-  the same quantity over all K rows directly, so the tapped value is
+  state rows (e.g. the participants' error-feedback residuals). The
+  stacked-clients round computes these partials over its rows (device-local
+  on a mesh) and rides them on the round's single fused ``psum`` (no
+  extra rendezvous, no host sync); the scan round sums the same quantity
+  over all K rows in :func:`collect`, so the tapped value is
   driver-independent.
 - :func:`probe_taps` — the loss's own counters (``loss_fn.probe``),
   summed over the round's clients by the engine; an MoE model's
@@ -41,7 +41,7 @@ import jax.numpy as jnp
 def client_sqsums(client: dict) -> dict:
     """Per-entry sum of squares over every leaf of the round's client-state
     rows: ``{name: f32 scalar}``. Additive over the client axis, so the
-    mesh engine can psum per-device partials into the global value."""
+    stacked-clients round can psum per-device partials on a mesh."""
     out = {}
     for name, rows in client.items():
         parts = [jnp.sum(jnp.square(l.astype(jnp.float32)))
@@ -67,7 +67,7 @@ def collect(strategy, state: Optional[dict], selection, divs, umap,
 
     ``state`` is the round-local post-``update_state`` view (client rows
     included off-mesh). ``client_sq`` carries pre-reduced client partials
-    when the caller already psum'd them (the mesh engine); ``None`` means
+    when the caller already reduced them (the stacked round); ``None`` means
     compute them here from ``state['client']``. ``extra`` merges
     engine-side taps that no hook can see — e.g. the packed uplink's
     per-unit wire bytes and bit-width allocation (replicated values; keys

@@ -10,7 +10,8 @@ from repro.core import selection as sel
 from repro.core.units import UnitMap
 from repro.data import (FederatedData, dirichlet_partition, iid_partition,
                         make_image_dataset)
-from repro.federated import FLConfig, build_round_fn, run_training
+from repro.federated import (FedADPOptions, FLConfig, build_round_fn,
+                             run_training)
 from repro.federated.client import make_local_update
 from repro.models import cnn
 from repro.models import transformer as tfm
@@ -193,6 +194,71 @@ def test_topn_insert_matches_lax_top_k(n):
     _assert_close_rel(got, want)
 
 
+def _np_fedadp_masks(local, glob, keep):
+    """FedADP's neuron masks in numpy: each leaf keeps the ``keep``
+    fraction of its output neurons (last axis) that moved most in L2."""
+    delta = local.astype(np.float64) - glob.astype(np.float64)
+    scores = (np.abs(delta) if delta.ndim == 1 else
+              np.sqrt((delta ** 2).sum(axis=tuple(range(delta.ndim - 1)))))
+    out = scores.shape[0]
+    mask = np.zeros(out)
+    mask[np.argsort(-scores)[:max(1, int(round(keep * out)))]] = 1.0
+    return np.broadcast_to(mask, local.shape)
+
+
+def _np_round_oracle(algo, c, locals_, selection, keep):
+    """The round's new model in plain numpy from the clients' locals:
+    Eq. 5 per unit over ``selection`` (fedldf), or FedADP's element-wise
+    masked mean; the global model wherever no client contributes."""
+    w = np.asarray(c["sizes"], np.float64)
+    s = np.asarray(selection, np.float64)
+    out = {}
+    for key, (off, n) in c["umap"].spans.items():
+        def one(loc, glob):
+            loc = np.asarray(loc, np.float64)
+            glob = np.asarray(glob, np.float64)
+            if algo == "fedadp":
+                wk = np.stack([w[i] * _np_fedadp_masks(loc[i], glob, keep)
+                               for i in range(len(w))])
+            else:
+                # per-(client, unit) weights broadcast over each unit's
+                # slice of the leaf (one unit per row of a stacked leaf)
+                sw = s[:, off:off + n] * w[:, None]
+                shape = ((len(w), n) if n > 1 else (len(w),)) + \
+                    (1,) * (glob.ndim - (1 if n > 1 else 0))
+                wk = np.broadcast_to(sw.reshape(shape), loc.shape)
+            num, den = (wk * loc).sum(0), wk.sum(0)
+            return np.where(den > 0, num / np.where(den > 0, den, 1.0), glob)
+
+        out[key] = jax.tree.map(one, locals_[key], c["params"][key])
+    return out
+
+
+@pytest.mark.parametrize("algo", ["fedldf", "fedadp"])
+def test_vmap_round_matches_numpy_oracle(vgg_setup, algo):
+    """The single-device stacked round aggregates through the strategy's
+    psum_parts/psum_finalize halves with an identity reduce between them:
+    its new model equals a plain numpy Eq. 5 (a top-n selection) or
+    FedADP element-wise masked mean over the same clients' locals and the
+    round's own selection, to float32 rounding."""
+    # every client a different data size, so the weights show
+    c, keep = dict(vgg_setup, sizes=5.0 * jnp.arange(1.0, 7.0)), 0.25
+    opts = FedADPOptions(keep=keep) if algo == "fedadp" else None
+    fl = FLConfig(algo=algo, clients_per_round=c["k"], top_n=2, mode="vmap",
+                  lr=c["lr"], algo_options=opts)
+    new, m = jax.jit(build_round_fn(c["loss"], c["umap"], fl))(
+        c["params"], c["batch"], c["sizes"], c["key"])
+    selection = np.asarray(m["selection"])
+    if algo == "fedldf":
+        assert selection.sum(0).tolist() == [2] * c["umap"].num_units
+    locals_ = jax.device_get(_client_locals(c))
+    want = _np_round_oracle(algo, c, locals_, selection, keep)
+    _assert_close_rel(new, want)
+    moved = max(float(jnp.abs(a - b).max()) for a, b in
+                zip(jax.tree.leaves(new), jax.tree.leaves(c["params"])))
+    assert moved > 1e-4
+
+
 def test_fedldf_nK_equals_fedavg(setup):
     """Theorem 1 degeneracy: n = K ⇒ FedLDF ≡ FedAvg exactly."""
     params, umap, batch, sizes, key, k = setup
@@ -228,7 +294,7 @@ def test_fedadp_runs_and_prunes(setup):
     assert float(c["uplink_payload"]) == \
         pytest.approx(0.25 * float(c["fedavg_uplink"]))
     # scan mode (unlocked by the strategy refactor): the engine stacks the
-    # sequentially-trained locals and feeds the same aggregate hook, so
+    # sequentially-trained locals and feeds the same aggregation halves, so
     # the two layouts agree on a fixed seed.
     fl_scan = FLConfig(algo="fedadp", clients_per_round=k, fedadp_keep=0.25,
                        mode="scan")

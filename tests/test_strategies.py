@@ -173,7 +173,8 @@ def test_fedadp_mesh_capability_flipped():
 # ----------------------------------------------------------------------
 def test_fedadp_vmap_scan_trajectory_equivalence(fed_data):
     """Multi-round driver equivalence on a fixed seed: the scan engine
-    stacks sequentially-trained locals into the same aggregate hook."""
+    stacks sequentially-trained locals into the same psum_parts /
+    psum_finalize halves."""
     kw = dict(algo="fedadp", num_clients=8, clients_per_round=4, top_n=2,
               lr=0.05, batch_per_client=8, fedadp_keep=0.3)
     p0 = cnn.init_params(jax.random.PRNGKey(0), CFG)
