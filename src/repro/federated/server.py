@@ -1329,6 +1329,17 @@ def _build_block_fn(loss_fn, umap: UnitMap, flcfg: FLConfig):
     return run_block
 
 
+@jax.jit
+def _copy_carry(params, state):
+    """The scan carry's first value as one program: copies of ``params``
+    and of a resumed ``state`` (``None`` passes through), and a zeroed
+    comm accumulator. ``run_block`` donates its carry, so every output is
+    a buffer of its own and the caller's params and state survive the
+    call. Not donated; the outputs keep the inputs' shardings."""
+    return (jax.tree.map(jnp.copy, params), jax.tree.map(jnp.copy, state),
+            comm_mod.comm_acc_init())
+
+
 def run_training_scan(params: Pytree, loss_fn, fldata, flcfg: FLConfig,
                       rounds: int,
                       eval_fn: Optional[Callable[[Pytree], float]] = None,
@@ -1393,17 +1404,15 @@ def _run_training_scan(params, loss_fn, fldata, flcfg, rounds, eval_fn,
         merged = ((lambda p: p) if partition is None
                   else (lambda p: partition.merge(p, frozen)))
     with prof_mod.span("fl.scan.copy_carry"):
-        # run_block donates its carry; copy once so the caller's param and
-        # resumed-state buffers survive the first block
-        params = jax.tree.map(jnp.copy, params)
-        if server_state is not None:
-            state0 = jax.tree.map(jnp.copy, (
-                _place_state(server_state, params, strategy, flcfg.mesh)
-                if flcfg.mesh is not None else server_state))
-        else:
+        # a fresh state is new buffers already: only the caller's are copied
+        if server_state is not None and flcfg.mesh is not None:
+            server_state = _place_state(server_state, params, strategy,
+                                        flcfg.mesh)
+        params, state0, acc = _copy_carry(params, server_state)
+        if server_state is None:
             state0 = strategy.init_state(params, flcfg.num_clients,
                                          flcfg.mesh)
-        carry = (params, state0, comm_mod.comm_acc_init())
+        carry = (params, state0, acc)
     log = TrainLog()
     tele = flcfg.telemetry
     sink = ProgressSink.for_run(tele, verbose)

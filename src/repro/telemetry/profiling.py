@@ -17,8 +17,9 @@ Independent facilities the round drivers wire in:
   (``run_training_scan``) opens ``fl.scan`` per call (stats
   ``start_round``, ``rounds``) around ``fl.scan.prepare`` (unit map,
   strategy, engine-cache lookup, mesh placement),
-  ``fl.scan.copy_carry`` (copies of params and resumed state before
-  donation, or ``init_state``; the comm accumulator), and per eval
+  ``fl.scan.copy_carry`` (one program copies params and a resumed state
+  before donation and zeroes the comm accumulator; a fresh run's
+  ``init_state`` after it), and per eval
   block ``fl.scan.dispatch`` (sizes, base key, the block call: a compile
   shows here), ``fl.scan.pull``, ``fl.scan.log`` and, with an eval
   function, ``fl.scan.eval``; then ``fl.scan.finish``. The host driver

@@ -4,9 +4,16 @@ Covers the seam itself (a toy rotating-selection strategy whose trajectory
 depends on its state must agree across the host-vmap, jitted-scan, and
 mesh-sharded drivers; stateless strategies must pay zero carry overhead),
 the EF residual store re-expressed as declared client state, server-state
-checkpoint round-trips (save → load → continue bit-identically), and the
-FedLAMA proof strategy (round-0 full sync, interval adaptation, driver
-agreement, uplink below FedAvg)."""
+checkpoint round-trips (save → load → continue bit-identically), the
+scan driver's first carry (one copy program that leaves the caller's
+params and state live, on one device and on a mesh; one-round calls equal
+one run), and the FedLAMA proof strategy (round-0 full sync, interval
+adaptation, driver agreement, uplink below FedAvg)."""
+
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -14,12 +21,14 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import load_server_state, save_server_state
+from repro.core.wire import CompressionConfig
 from repro.data import FederatedData, iid_partition, make_image_dataset
 from repro.federated import (FLConfig, FLStrategy, build_round_fn,
                              make_strategy, register_strategy, run_training,
                              run_training_scan, unregister_strategy)
 from repro.launch.mesh import make_client_mesh
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_CLIENTS, K = 8, 4
 STATELESS = ("fedldf", "fedavg", "random", "hdfl", "fedadp", "fedlp")
 
@@ -251,6 +260,163 @@ def test_save_load_stateless_round_trip(tmp_path):
     p2, state = load_server_state(path)
     _assert_trees_equal(params, p2)
     assert state is None
+
+
+# ----------------------------------------------------------------------
+# The scan driver's first carry: one program, the caller's buffers kept
+# ----------------------------------------------------------------------
+Q8EF = dict(compression=CompressionConfig(bits=8, error_feedback=True))
+
+
+def _snapshot(tree):
+    return jax.tree.map(np.array, tree)
+
+
+def _assert_survived(tree, snap):
+    """Every leaf of ``tree`` is still live and holds ``snap``'s values."""
+    for leaf, old in zip(jax.tree.leaves(tree), jax.tree.leaves(snap)):
+        assert not leaf.is_deleted()
+        np.testing.assert_array_equal(np.asarray(leaf), old)
+
+
+@pytest.mark.parametrize("kw", [{}, Q8EF],
+                         ids=["fedldf", "q8ef_resumed"])
+def test_scan_call_keeps_caller_buffers(task, kw):
+    """``run_block`` donates its carry; the call copies first, so the
+    caller's params and resumed server state are neither deleted nor
+    changed, over a call of two eval blocks."""
+    params, data = task
+    fl = _cfg(**kw)
+    state = None
+    if kw:
+        params, log = run_training_scan(params, _loss, data, fl, rounds=1,
+                                        seed=0)
+        state = log.final_state
+        assert state is not None
+    snap_p, snap_s = _snapshot(params), _snapshot(state)
+    eval_fn = jax.jit(lambda p: jnp.float32(0.0))
+    p_new, _ = run_training_scan(params, _loss, data, fl, rounds=2, seed=0,
+                                 start_round=1, server_state=state,
+                                 eval_fn=eval_fn, eval_every=1)
+    _assert_survived(params, snap_p)
+    _assert_survived(state, snap_s)
+    assert any(not np.array_equal(np.asarray(a), b) for a, b in
+               zip(jax.tree.leaves(p_new), jax.tree.leaves(snap_p)))
+
+
+def test_scan_carry_copy_traces_once(task, monkeypatch):
+    """Calls with the same tree structure reuse one carry-copy program:
+    ``comm_acc_init`` runs only while ``_copy_carry`` traces."""
+    from repro.core import comm as comm_mod
+    from repro.federated import server
+    params, data = task
+    fl = _cfg(**Q8EF)
+    traces = []
+    init = comm_mod.comm_acc_init
+    monkeypatch.setattr(comm_mod, "comm_acc_init",
+                        lambda: traces.append(1) or init())
+    server._copy_carry.clear_cache()
+    p, state = params, None
+    for t in range(4):
+        p, log = run_training_scan(p, _loss, data, fl, rounds=1, seed=0,
+                                   start_round=t, server_state=state)
+        state = log.final_state
+        # the fresh first call traces once, the resumed calls once more
+        assert len(traces) == min(t + 1, 2), t
+
+
+def _per_round_bytes(logs):
+    """Integer uplink bytes of each round, from one or more calls' logs
+    (``uplink_mb`` is cumulative within a call)."""
+    out = []
+    for log in logs:
+        cum = np.rint(np.asarray(log.uplink_mb, np.float64) * 1e6)
+        out.extend(np.diff(np.concatenate(([0.0], cum))).tolist())
+    return out
+
+
+@pytest.mark.parametrize("kw", [{}, Q8EF],
+                         ids=["fedldf", "q8ef"])
+def test_one_round_calls_match_one_run(task, kw):
+    """Four one-round calls chained through ``start_round`` and
+    ``server_state`` (how a benchmark or a checkpointing user drives the
+    engine) equal one four-round call bit for bit: params, losses, uplink
+    bytes and the final state."""
+    params, data = task
+    fl = _cfg(**kw)
+    p_full, l_full = run_training_scan(params, _loss, data, fl, rounds=4,
+                                       seed=0)
+    p, state, logs = params, None, []
+    for t in range(4):
+        p, log = run_training_scan(p, _loss, data, fl, rounds=1, seed=0,
+                                   start_round=t, server_state=state)
+        state = log.final_state
+        logs.append(log)
+    _assert_trees_equal(p_full, p)
+    assert l_full.losses == [x for log in logs for x in log.losses]
+    assert _per_round_bytes([l_full]) == _per_round_bytes(logs)
+    assert min(_per_round_bytes(logs)) > 0
+    if kw:
+        _assert_trees_equal(l_full.final_state, state)
+    else:
+        assert l_full.final_state is None and state is None
+
+
+MESH_CARRY_SCRIPT = textwrap.dedent("""
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core.wire import CompressionConfig
+    from repro.data import FederatedData, iid_partition, make_image_dataset
+    from repro.federated import FLConfig, run_training_scan, server
+    from repro.launch.mesh import make_client_mesh
+    from repro.launch.sharding import fl_param_specs, to_named
+
+    def loss(p, b):
+        x = b["images"].reshape(b["images"].shape[0], -1)
+        return ((x @ p["w"] + p["b"]) ** 2).mean()
+
+    train, _ = make_image_dataset(num_train=128, num_test=8, seed=1)
+    data = FederatedData(train.xs, train.ys, iid_partition(train.ys, 8, 0))
+    mesh = make_client_mesh(2, model=2)
+    fl = FLConfig(num_clients=8, clients_per_round=4, top_n=1,
+                  batch_per_client=4, mesh=mesh,
+                  compression=CompressionConfig(bits=8, error_feedback=True))
+    params = {"w": jnp.ones((3072, 4)) * 0.01, "b": jnp.zeros((4,))}
+    params = jax.device_put(params, to_named(fl_param_specs(params, mesh),
+                                             mesh))
+    params, log = run_training_scan(params, loss, data, fl, rounds=1)
+    state = log.final_state
+    leaves = jax.tree.leaves((params, state))
+    snap = [np.array(x) for x in leaves]
+    shardings = [x.sharding for x in leaves]
+    assert any(not s.is_fully_replicated for s in shardings), shardings
+    copied = server._copy_carry(params, state)
+    assert [x.sharding for x in jax.tree.leaves(copied[:2])] == shardings
+    assert all(a is not b for a, b in zip(jax.tree.leaves(copied[:2]),
+                                          leaves))
+    p2, log2 = run_training_scan(params, loss, data, fl, rounds=2,
+                                 start_round=1, server_state=state)
+    for x, old in zip(leaves, snap):
+        assert not x.is_deleted()
+        np.testing.assert_array_equal(np.asarray(x), old)
+    out = [x.sharding for x in jax.tree.leaves((p2, log2.final_state))]
+    assert out == shardings, (out, shardings)
+    print("ok")
+""")
+
+
+def test_scan_call_keeps_caller_buffers_on_mesh():
+    """On a 2-device ('clients' 1, 'model' 2) mesh the carry copy keeps
+    each input's ``NamedSharding`` (the scan carry's sharding stays
+    fixed), and the caller's placed params and EF state survive the call.
+    Own process: the suite runs on one CPU device."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"),
+                                           ROOT]))
+    out = subprocess.run([sys.executable, "-c", MESH_CARRY_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().splitlines()[-1] == "ok"
 
 
 # ----------------------------------------------------------------------
